@@ -62,8 +62,11 @@ def _parity_cases():
     qd, kd, vd, q_pos, k_pos = _attention_inputs(decode=True)
     dec_expect = ref.reference_attention(qd, kd, vd, q_positions=q_pos,
                                          k_positions=k_pos)
+    # Decode reads a head-major (B, Hkv, T, D) cache; the oracle above takes
+    # token-major K/V.
+    kd_cache, vd_cache = jnp.swapaxes(kd, 1, 2), jnp.swapaxes(vd, 1, 2)
     cases.append(("attention.decode", "pallas", lambda kc: (
-        ops.decode_attention(qd, kd, vd, q_positions=q_pos,
+        ops.decode_attention(qd, kd_cache, vd_cache, q_positions=q_pos,
                              k_positions=k_pos, kernel=kc), dec_expect)))
 
     x = jax.random.normal(jax.random.PRNGKey(1), (_B, _S, 64))

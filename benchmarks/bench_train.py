@@ -91,19 +91,6 @@ def _make_trainer(arch, *, policy=None, fp8=False, memopt=None, depth=None,
     return cfg.instantiate()
 
 
-def _step_cost(trainer):
-    """Compiled-step cost via the trainer's own observability hook
-    (``step_cost_analysis``: flops, bytes_accessed, peak_hbm_proxy_bytes),
-    with a parameter-bytes fallback when the backend reports nothing."""
-    cost = dict(trainer.step_cost_analysis())
-    if not cost.get("peak_hbm_proxy_bytes"):
-        state = jax.eval_shape(trainer.init_state)
-        cost["peak_hbm_proxy_bytes"] = sum(
-            l.size * l.dtype.itemsize
-            for l in jax.tree.leaves(state) if hasattr(l, "size"))
-    return cost
-
-
 def _train_bench(arch, *, policy=None, fp8=False, steps=8, batch=8, seq=32):
     trainer = _make_trainer(arch, policy=policy, fp8=fp8, steps=steps,
                             batch=batch, seq=seq)
@@ -114,7 +101,8 @@ def _train_bench(arch, *, policy=None, fp8=False, steps=8, batch=8, seq=32):
     result = trainer.run(num_steps=steps)
     wall = time.perf_counter() - t0
     per_step = wall / steps
-    cost = _step_cost(trainer)
+    # Compiled-step cost: flops, bytes_accessed, peak_hbm_proxy_bytes.
+    cost = trainer.step_cost_analysis()
     n_dev = max(len(jax.devices()), 1)
     mfu = estimate_mfu(cost.get("flops"), per_step, num_devices=n_dev)
     return {
@@ -126,8 +114,8 @@ def _train_bench(arch, *, policy=None, fp8=False, steps=8, batch=8, seq=32):
         "tokens_per_s": batch * seq / per_step,
         "tokens_per_s_per_device": batch * seq / per_step / n_dev,
         "step_flops": cost.get("flops"),
-        # Achieved/peak model FLOP/s on THIS backend (CPU here: tracks
-        # relative movement, not an accelerator-meaningful absolute).
+        # Achieved/peak model FLOP/s of the device; None on the CPU, which
+        # has no device peak.
         "mfu": mfu,
         "num_params": int(result["num_params"]),
         "peak_hbm_proxy_bytes": cost["peak_hbm_proxy_bytes"],
@@ -156,7 +144,7 @@ def _memopt_bench(rows):
         t0 = time.perf_counter()
         result = trainer.run(num_steps=MEMOPT_STEPS)
         per_step = (time.perf_counter() - t0) / MEMOPT_STEPS
-        cost = _step_cost(trainer)
+        cost = trainer.step_cost_analysis()
         entry = {
             "opt_state_bytes": int(result["opt_state_bytes"]),
             "peak_hbm_proxy_bytes": cost["peak_hbm_proxy_bytes"],
@@ -187,10 +175,8 @@ def _memopt_bench(rows):
 
 def _fleet_bench(*, world=2, steps=6):
     """Elastic multi-process goodput: a real 2-worker fleet (subprocess
-    workers, file-backed collectives) trained to completion; reports the
-    aggregated fleet goodput. Returns None when the elastic path cannot run
-    here (e.g. no subprocess spawning) — the fleet fields then simply do
-    not appear in BENCH_train.json."""
+    workers on the CPU, file-backed collectives) trained to completion;
+    reports the aggregated fleet goodput. A failing fleet raises."""
     import shutil
     import tempfile
 
@@ -212,8 +198,6 @@ def _fleet_bench(*, world=2, steps=6):
             "rank_seconds": g["rank_seconds"],
             "productive_s": g["productive_s"],
         }
-    except Exception:  # noqa: BLE001 — elastic path unavailable: omit fields
-        return None
     finally:
         shutil.rmtree(wd, ignore_errors=True)
 
@@ -297,11 +281,10 @@ def run():
     LAST_JSON = {"archs": archs_json, "roofline": roofline,
                  "fp8_train_parity": fp8_json, "memopt": memopt_json}
     fleet = _fleet_bench()
-    if fleet is not None:  # fleet fields only when the elastic path ran
-        LAST_JSON["fleet"] = fleet
-        rows.append((
-            "train_fleet_goodput", fleet["rank_seconds"] * 1e6,
-            f"world={fleet['world_size']};"
-            f"goodput={fleet['fleet_goodput_fraction']:.3f};"
-            f"steady={fleet['fleet_steady_goodput_fraction']:.3f}"))
+    LAST_JSON["fleet"] = fleet
+    rows.append((
+        "train_fleet_goodput", fleet["rank_seconds"] * 1e6,
+        f"world={fleet['world_size']};"
+        f"goodput={fleet['fleet_goodput_fraction']:.3f};"
+        f"steady={fleet['fleet_steady_goodput_fraction']:.3f}"))
     return rows

@@ -15,7 +15,9 @@
 
 Prints ``name,us_per_call,derived`` CSV. Modules may expose a ``LAST_JSON``
 dict after ``run()``; it is persisted as ``BENCH_<suffix>.json`` next to the
-CWD so the perf trajectory (e.g. decode TPOT) is tracked across PRs.
+CWD so the perf trajectory (e.g. decode TPOT) is tracked across PRs. A
+module that raises is reported and the harness exits non-zero after the
+remaining modules ran.
 """
 
 import json
@@ -24,6 +26,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.core.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     from benchmarks import (
         bench_checkpoint,
         bench_inference,
@@ -36,13 +41,15 @@ def main() -> None:
     )
 
     print("name,us_per_call,derived")
+    failed = []
     for mod in (bench_loc, bench_kernels, bench_train, bench_checkpoint,
                 bench_inference, bench_serving, bench_scaling,
                 bench_observability):
         try:
             rows = mod.run()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — reported; exit code below
             traceback.print_exc()
+            failed.append(mod.__name__)
             rows = [(f"{mod.__name__}/ERROR", -1, str(e)[:80])]
         for name, us, derived in rows:
             print(f"{name},{us:.2f},{derived}")
@@ -54,6 +61,8 @@ def main() -> None:
             with open(path, "w") as f:
                 json.dump(payload, f, indent=2, sort_keys=True)
             print(f"# wrote {path}", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -36,12 +36,12 @@ MODEL_AXIS = 16  # production mesh model-axis size
 
 
 def kv_cache_spec(num_kv_heads: int, head_dim: int):
-    """(B, T, Hkv, D) cache sharding: heads on "model" when divisible;
+    """(B, Hkv, T, D) cache sharding: heads on "model" when divisible;
     otherwise shard the SEQUENCE dim over "model" (flash-decoding layout —
     per-shard partial softmax, GSPMD inserts the combine)."""
     if num_kv_heads % MODEL_AXIS == 0:
-        return (("pod", "data"), None, "model", None)
-    return (("pod", "data"), "model", None, None)
+        return (("pod", "data"), "model", None, None)
+    return (("pod", "data"), None, "model", None)
 
 
 def expert_specs(num_experts: int):

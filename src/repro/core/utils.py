@@ -10,6 +10,7 @@ production mesh unchanged.
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple, Union
 
@@ -21,6 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 __all__ = [
     "PartitionSpecLike",
     "make_mesh",
+    "infer_mesh_shape",
     "set_mesh",
     "current_mesh",
     "resolve_spec",
@@ -40,14 +42,29 @@ __all__ = [
 PartitionSpecLike = Optional[Sequence[Union[str, Tuple[str, ...], None]]]
 
 
-def make_mesh(shape, axis_names) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where this jax supports them
-    (``jax.sharding.AxisType`` only exists in newer releases)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axis_names))
+def make_mesh(shape, axis_names, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axis types (GSPMD resolves shardings
+    from the partition specs the layers carry). ``devices`` defaults to
+    every device present."""
     return jax.make_mesh(tuple(shape), tuple(axis_names),
-                         axis_types=(axis_type.Auto,) * len(shape))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
+def infer_mesh_shape(shape, num_devices: int) -> Tuple[int, ...]:
+    """Resolves a ``-1`` entry of a mesh shape to the devices the other axes
+    leave over, so one rule fits every slice size (``(-1, 1)`` is a
+    ``(1, 1)`` mesh on one chip and ``(4, 1)`` on four)."""
+    shape = tuple(int(s) for s in shape)
+    if shape.count(-1) > 1:
+        raise ValueError(f"mesh shape {shape} has more than one -1")
+    if -1 not in shape:
+        return shape
+    rest = math.prod(s for s in shape if s != -1)
+    if num_devices % rest:
+        raise ValueError(f"mesh shape {shape} does not divide "
+                         f"{num_devices} devices")
+    return tuple(num_devices // rest if s == -1 else s for s in shape)
 
 
 class _MeshHolder(threading.local):

@@ -21,6 +21,11 @@ blocks from (q, k, lse) instead of materializing the (S, T) matrix —
     sequential) and accumulates dK/dV in VMEM scratch.
   * **dQ pass**: grid mirrors the forward; dQ accumulates over KV blocks.
 
+Per-row statistics (the forward's LSE, the backward's LSE and delta) cross
+the kernel boundary lane-broadcast, ``(rows, 128)`` f32, so their blocks tile
+like any other; the residual saved for the backward is the compact
+``(B, Hq, S)`` LSE, broadcast again only for the backward call.
+
 Both passes are GQA- and sliding-window-aware and validated against
 ``jax.grad`` of ``repro.kernels.ref.reference_attention`` in interpret mode
 (CPU), so the flash path is legal under ``jax.grad`` on every platform.
@@ -36,8 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 __all__ = ["flash_attention", "flash_attention_forward"]
 
@@ -79,7 +82,7 @@ def _fwd_kernel(
     k_ref,  # (1, block_k, D)
     v_ref,  # (1, block_k, D)
     o_ref,  # (1, block_q, D)
-    lse_ref,  # (1, block_q) f32
+    lse_ref,  # (1, block_q, _LANES) f32, lane-broadcast
     m_scr,  # (block_q, _LANES) f32
     l_scr,  # (block_q, _LANES) f32
     acc_scr,  # (block_q, D) f32
@@ -145,8 +148,8 @@ def _fwd_kernel(
         l = l_scr[:, 0:1]
         denom = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
-        m = m_scr[:, 0]
-        lvec = l_scr[:, 0]
+        m = m_scr[...]
+        lvec = l_scr[...]
         # lse = m + log(l); NEG_INF marks fully-masked (invalid) rows so the
         # backward can zero their probability blocks.
         lse_ref[0] = jnp.where(lvec > 0.0,
@@ -201,7 +204,7 @@ def _fwd_impl(
         return (b * Hkv + h // G, kj, 0)
 
     def lse_index(bh, qi, kj):
-        return (bh, qi)
+        return (bh, qi, 0)
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -226,25 +229,25 @@ def _fwd_impl(
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_q), lse_index),
+            pl.BlockSpec((1, block_q, _LANES), lse_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, S_pad, D), q.dtype),
-            jax.ShapeDtypeStruct((B * Hq, S_pad), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hq, S_pad, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(qh, kh, vh)
 
     out = out.reshape(B, Hq, S_pad, D).transpose(0, 2, 1, 3)[:, :S]
-    lse = lse.reshape(B, Hq, S_pad)[:, :, :S]
+    lse = lse[..., 0].reshape(B, Hq, S_pad)[:, :, :S]
     return out, lse
 
 
@@ -311,8 +314,8 @@ def _dq_kernel(
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0][:, 0:1]
+        delta = delta_ref[0][:, 0:1]
         _, ds = _recompute_p_ds(q, k, lse, do, v, delta, mask,
                                 logit_softcap=logit_softcap, scale=scale)
         # dq += scale * ds @ k
@@ -364,8 +367,8 @@ def _dkv_kernel(
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0][:, 0:1]
+        delta = delta_ref[0][:, 0:1]
         p, ds = _recompute_p_ds(q, k, lse, do, v, delta, mask,
                                 logit_softcap=logit_softcap, scale=scale)
         # dv += p^T @ do ; dk += scale * ds^T @ q
@@ -420,6 +423,9 @@ def _bwd_impl(
         # Padding rows are invalid: lse = NEG_INF zeroes their p blocks.
         lse_h = jnp.pad(lse_h, ((0, 0), (0, S_pad - S)),
                         constant_values=NEG_INF)
+    # Lane-broadcast row statistics, (B*Hq, S_pad, _LANES).
+    lse_h = jnp.broadcast_to(lse_h[..., None], (*lse_h.shape, _LANES))
+    delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
 
     num_q_blocks = S_pad // block_q
     num_kv_blocks = T_pad // block_k
@@ -432,7 +438,7 @@ def _bwd_impl(
         return (b * Hkv + h // G, kj, 0)
 
     def lse_index(bh, qi, kj):
-        return (bh, qi)
+        return (bh, qi, 0)
 
     common = dict(
         block_q=block_q, block_k=block_k, q_len=S, kv_len=T,
@@ -448,13 +454,13 @@ def _bwd_impl(
             pl.BlockSpec((1, block_k, D), kv_index),
             pl.BlockSpec((1, block_k, D), kv_index),
             pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_q), lse_index),
-            pl.BlockSpec((1, block_q), lse_index),
+            pl.BlockSpec((1, block_q, _LANES), lse_index),
+            pl.BlockSpec((1, block_q, _LANES), lse_index),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), q_index),
         out_shape=jax.ShapeDtypeStruct((B * Hq, S_pad, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -471,7 +477,7 @@ def _bwd_impl(
 
     def lse_group_index(bhkv, kj, t):
         row = (bhkv // Hkv) * Hq + (bhkv % Hkv) * G + t // num_q_blocks
-        return (row, t % num_q_blocks)
+        return (row, t % num_q_blocks, 0)
 
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q_blocks=num_q_blocks,
@@ -482,8 +488,8 @@ def _bwd_impl(
             pl.BlockSpec((1, block_k, D), kv_self_index),
             pl.BlockSpec((1, block_k, D), kv_self_index),
             pl.BlockSpec((1, block_q, D), q_group_index),
-            pl.BlockSpec((1, block_q), lse_group_index),
-            pl.BlockSpec((1, block_q), lse_group_index),
+            pl.BlockSpec((1, block_q, _LANES), lse_group_index),
+            pl.BlockSpec((1, block_q, _LANES), lse_group_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), kv_self_index),
@@ -497,7 +503,7 @@ def _bwd_impl(
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

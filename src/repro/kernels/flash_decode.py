@@ -23,6 +23,13 @@ Design points:
   * ``interpret=True`` runs the same kernel body under the Pallas
     interpreter for CPU validation (config choice, not code change: §4.2).
 
+Cache layout is head-major — ``(B, Hkv, T, D)`` for the contiguous cache and
+``(P, Hkv, page, D)`` for the paged pool — so each grid step's K/V block is a
+``(rows, D)`` tile whose last two dims satisfy the TPU tiling rule for any
+head count. Positions stay ``(B, T)`` / ``(P, page)``: the kernels fetch an
+8-row slab of the position array (one sublane tile) and select their row in
+VMEM, so neither the cache nor the positions is copied per decode step.
+
 Forward only — decode is inference-only by construction. The kernel expects
 a single-device or replicated KV cache: with sequence-sharded caches, use
 the reference decode path (backend ``"ref"``), which constrains the
@@ -49,12 +56,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
-
 __all__ = ["flash_decode_forward", "paged_flash_decode_forward"]
 
 NEG_INF = -1e30
 _LANES = 128  # VREG lane count: scratch second-minor dim
+_SUBLANES = 8  # rows of one 32-bit VREG tile: position slabs are this tall
 
 
 def _round_up(x: int, m: int) -> int:
@@ -114,21 +120,23 @@ def _decode_mask(q_pos, k_pos, *, causal, sliding_window):
 
 def _kernel(
     q_ref,  # (1, 1, R, D): rows = S' decode steps x G grouped query heads
-    k_ref,  # (1, block_k, 1, D)
-    v_ref,  # (1, block_k, 1, D)
-    qpos_ref,  # (1, R) int32, -1 = padding row
-    kpos_ref,  # (1, block_k) int32, -1 = empty cache slot
+    k_ref,  # (1, 1, block_k, D)
+    v_ref,  # (1, 1, block_k, D)
+    qpos_ref,  # (1, R, 1) int32, -1 = padding row
+    kpos_ref,  # (slab, block_k) int32 rows of batch b // slab, -1 = empty
     o_ref,  # (1, 1, R, D)
     m_scr,  # (R, _LANES) f32
     l_scr,  # (R, _LANES) f32
     acc_scr,  # (R, D) f32
     *,
     num_kv_blocks: int,
+    kv_len: Optional[int],
     causal: bool,
     sliding_window: Optional[int],
     logit_softcap: Optional[float],
     scale: float,
 ):
+    b = pl.program_id(0)
     kj = pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -136,10 +144,20 @@ def _kernel(
         _init_scratch(m_scr, l_scr, acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (R, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-    mask = _decode_mask(qpos_ref[0][:, None], kpos_ref[0][None, :],
-                        causal=causal, sliding_window=sliding_window)
+    k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+    v = v_ref[0, 0].astype(jnp.float32)  # (bk, D)
+    k_pos = kpos_ref[pl.ds(b % kpos_ref.shape[0], 1), :]  # (1, bk)
+    mask = _decode_mask(qpos_ref[0], k_pos, causal=causal,
+                        sliding_window=sliding_window)
+    if kv_len is not None:
+        # Last block runs past the cache: its tail holds whatever the DMA
+        # brought in, positions included. Mask those columns, and zero
+        # their V rows so that 0 * garbage cannot reach the accumulator.
+        bk = k.shape[0]
+        col = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        mask = jnp.logical_and(mask, col < kv_len)
+        row = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        v = jnp.where(row < kv_len, v, 0.0)
     _online_block_update(q, k, v, mask, m_scr, l_scr, acc_scr,
                          logit_softcap=logit_softcap)
 
@@ -151,10 +169,10 @@ def _kernel(
 def _paged_kernel(
     tbl_ref,  # (B, N) int32 scalar-prefetch page table, -1 = unmapped
     q_ref,  # (1, 1, R, D)
-    k_ref,  # (1, page, 1, D): the physical page named by tbl[b, j]
-    v_ref,  # (1, page, 1, D)
-    qpos_ref,  # (1, R) int32, -1 = padding row
-    kpos_ref,  # (1, page) int32 per-token positions of the page, -1 = empty
+    k_ref,  # (1, 1, page, D): head h of the physical page named by tbl[b, j]
+    v_ref,  # (1, 1, page, D)
+    qpos_ref,  # (1, R, 1) int32, -1 = padding row
+    kpos_ref,  # (slab, page) int32 positions of pages phys // slab, -1 = empty
     *rest,  # [scale_ref (1, page, 2) f32 when has_scales,] o_ref, scratches
     num_logical_pages: int,
     causal: bool,
@@ -176,19 +194,19 @@ def _paged_kernel(
         _init_scratch(m_scr, l_scr, acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale
-    k = k_ref[0, :, 0, :].astype(jnp.float32)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)  # (page, D)
+    v = v_ref[0, 0].astype(jnp.float32)
     if scale_ref is not None:
         # Quantized pool: per-token-slot (k, v) scales ride in as a page-shaped
         # operand through the same scalar-prefetch table, so dequantization is
-        # in-VMEM and the HBM pool stays in its storage dtype. (Real-TPU note:
-        # int8 pools want page >= 32 for native tiling — min int8 tile is
-        # (32, 128); the interpreter used in CI accepts any page size.)
+        # in-VMEM and the HBM pool stays in its storage dtype.
         sc = scale_ref[0].astype(jnp.float32)  # (page, 2)
         k = k * sc[:, 0:1]
         v = v * sc[:, 1:2]
-    mask = _decode_mask(qpos_ref[0][:, None], kpos_ref[0][None, :],
-                        causal=causal, sliding_window=sliding_window)
+    phys = jnp.maximum(tbl_ref[b, j], 0)
+    k_pos = kpos_ref[pl.ds(phys % kpos_ref.shape[0], 1), :]  # (1, page)
+    mask = _decode_mask(qpos_ref[0], k_pos, causal=causal,
+                        sliding_window=sliding_window)
     # Unmapped logical pages were clamped to physical page 0 for the DMA;
     # masking on the TABLE entry (not the page contents) drops them exactly.
     mask = jnp.logical_and(mask, tbl_ref[b, j] >= 0)
@@ -202,11 +220,13 @@ def _paged_kernel(
 
 def _pack_q_rows(q: jax.Array, q_positions: jax.Array, Hkv: int):
     """(B, S', Hq, D) -> (B, Hkv, R_pad, D) rows of (s', g) pairs per KV
-    group, plus the per-row positions (-1 = padding row)."""
+    group, plus the per-row positions as a (B, R_pad, 1) column (-1 =
+    padding row). Rows pad to a whole tile of the query's dtype."""
     B, Sq, Hq, D = q.shape
     G = Hq // Hkv
     R = Sq * G
-    R_pad = _round_up(max(R, 8), 8)
+    row_tile = _SUBLANES * max(1, 4 // jnp.dtype(q.dtype).itemsize)
+    R_pad = _round_up(R, row_tile)
     # q: (B, S', Hkv*G, D) -> (B, Hkv, S'*G, D); head h = kv * G + g.
     qr = q.reshape(B, Sq, Hkv, G, D).transpose(0, 2, 1, 3, 4).reshape(B, Hkv, R, D)
     qpos_rows = jnp.repeat(q_positions, G, axis=1)  # (B, R): row r -> q_pos[r // G]
@@ -214,12 +234,18 @@ def _pack_q_rows(q: jax.Array, q_positions: jax.Array, Hkv: int):
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, R_pad - R), (0, 0)))
         qpos_rows = jnp.pad(qpos_rows, ((0, 0), (0, R_pad - R)),
                             constant_values=-1)
-    return qr, qpos_rows, R, R_pad
+    return qr, qpos_rows[:, :, None], R, R_pad
+
+
+def _pos_slab(n: int) -> int:
+    """Rows of a position block: one sublane tile, or the whole (short)
+    array. A trailing partial slab is fine — the selected row is in bounds."""
+    return min(n, _SUBLANES)
 
 
 def flash_decode_forward(
     q: jax.Array,  # (B, S', Hq, D), S' small (decode steps)
-    k: jax.Array,  # (B, T, Hkv, D) — the cache, any slot order
+    k: jax.Array,  # (B, Hkv, T, D) — the cache, any slot order
     v: jax.Array,
     q_positions: jax.Array,  # (B, S') absolute positions of the new tokens
     k_positions: jax.Array,  # (B, T) per-slot absolute positions, -1 = empty
@@ -232,7 +258,7 @@ def flash_decode_forward(
     interpret: bool = False,
 ) -> jax.Array:
     B, Sq, Hq, D = q.shape
-    _, T, Hkv, _ = k.shape
+    _, Hkv, T, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
     scale = (D ** -0.5) if scale is None else scale
@@ -242,20 +268,22 @@ def flash_decode_forward(
 
     qr, qpos_rows, R, R_pad = _pack_q_rows(q, q_positions, Hkv)
 
-    block_k = min(block_k, _round_up(T, 8))
-    T_pad = _round_up(T, block_k)
-    if T_pad != T:
-        k = jnp.pad(k, ((0, 0), (0, T_pad - T), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, T_pad - T), (0, 0), (0, 0)))
-        # Padding slots carry pos = -1 and are masked like empty slots.
-        k_positions = jnp.pad(k_positions, ((0, 0), (0, T_pad - T)),
-                              constant_values=-1)
-    num_kv_blocks = T_pad // block_k
+    # The whole cache in one tile when it fits; else lane-aligned tiles, the
+    # last one running past the cache (masked in the kernel — the cache is
+    # never padded, which would copy it every step).
+    if T <= block_k:
+        block_k = T
+    elif block_k % _LANES:
+        raise ValueError(f"decode block_k {block_k} must be a multiple of "
+                         f"{_LANES} for a cache of {T} slots")
+    num_kv_blocks = -(-T // block_k)
+    slab = _pos_slab(B)
 
     grid = (B, Hkv, num_kv_blocks)
     kernel = functools.partial(
         _kernel,
         num_kv_blocks=num_kv_blocks,
+        kv_len=T if T % block_k else None,
         causal=causal,
         sliding_window=sliding_window,
         logit_softcap=logit_softcap,
@@ -267,10 +295,10 @@ def flash_decode_forward(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, R_pad, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, j: (b, j, h, 0)),
-            pl.BlockSpec((1, R_pad), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
+            pl.BlockSpec((1, R_pad, 1), lambda b, h, j: (b, 0, 0)),
+            pl.BlockSpec((slab, block_k), lambda b, h, j: (b // slab, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, R_pad, D), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R_pad, D), q.dtype),
@@ -279,7 +307,7 @@ def flash_decode_forward(
             pltpu.VMEM((R_pad, _LANES), jnp.float32),
             pltpu.VMEM((R_pad, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -292,8 +320,8 @@ def flash_decode_forward(
 
 def paged_flash_decode_forward(
     q: jax.Array,  # (B, S', Hq, D), S' small (decode steps)
-    k_pool: jax.Array,  # (P, page, Hkv, D) — shared physical page pool
-    v_pool: jax.Array,  # (P, page, Hkv, D)
+    k_pool: jax.Array,  # (P, Hkv, page, D) — shared physical page pool
+    v_pool: jax.Array,  # (P, Hkv, page, D)
     pos_pool: jax.Array,  # (P, page) int32 per-token positions, -1 = empty
     page_tables: jax.Array,  # (B, N) int32 physical page ids, -1 = unmapped
     q_positions: jax.Array,  # (B, S') absolute positions of the new tokens
@@ -312,12 +340,11 @@ def paged_flash_decode_forward(
     DMAs that physical page — one page fetch per KV group, no HBM gather.
     Unmapped entries clamp to page 0 and are masked via the table entry.
 
-    On real TPUs ``page`` (the pool's second axis) should be a multiple of
-    the sublane count (8 for f32, 16 for bf16) for efficient tiling; the
-    interpreter accepts any size.
+    The K/V block of a grid step is one head of one page, ``(page, D)``:
+    the pool's own trailing dims, so every page size tiles.
     """
     B, Sq, Hq, D = q.shape
-    P, page, Hkv, _ = k_pool.shape
+    P, Hkv, page, _ = k_pool.shape
     _, N = page_tables.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
@@ -340,25 +367,25 @@ def paged_flash_decode_forward(
         has_scales=has_scales,
     )
 
-    def phys(b, h, j, tbl):
-        del h
+    def phys(b, j, tbl):
         return jnp.maximum(tbl[b, j], 0)
 
+    slab = _pos_slab(P)
     in_specs = [
         pl.BlockSpec((1, 1, R_pad, D), lambda b, h, j, tbl: (b, h, 0, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, tbl: (phys(b, h, j, tbl), 0, h, 0)),
-        pl.BlockSpec((1, page, 1, D),
-                     lambda b, h, j, tbl: (phys(b, h, j, tbl), 0, h, 0)),
-        pl.BlockSpec((1, R_pad), lambda b, h, j, tbl: (b, 0)),
-        pl.BlockSpec((1, page),
-                     lambda b, h, j, tbl: (phys(b, h, j, tbl), 0)),
+        pl.BlockSpec((1, 1, page, D),
+                     lambda b, h, j, tbl: (phys(b, j, tbl), h, 0, 0)),
+        pl.BlockSpec((1, 1, page, D),
+                     lambda b, h, j, tbl: (phys(b, j, tbl), h, 0, 0)),
+        pl.BlockSpec((1, R_pad, 1), lambda b, h, j, tbl: (b, 0, 0)),
+        pl.BlockSpec((slab, page),
+                     lambda b, h, j, tbl: (phys(b, j, tbl) // slab, 0)),
     ]
     operands = [page_tables, qr, k_pool, v_pool, qpos_rows, pos_pool]
     if has_scales:
         # Dequant scales follow the same table-indexed page fetch as K/V.
         in_specs.append(pl.BlockSpec(
-            (1, page, 2), lambda b, h, j, tbl: (phys(b, h, j, tbl), 0, 0)))
+            (1, page, 2), lambda b, h, j, tbl: (phys(b, j, tbl), 0, 0)))
         operands.append(jnp.asarray(scale_pool, jnp.float32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -377,7 +404,7 @@ def paged_flash_decode_forward(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R_pad, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -13,6 +13,7 @@ spec. Resolution is memoized, so the hot-path overhead is one dict lookup.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -70,12 +71,17 @@ def flash_attention(
     scale: Optional[float] = None,
     kernel: Optional[KernelConfig] = None,
     needs_grad: bool = False,
+    partition=None,
 ) -> jax.Array:
     """Full-sequence attention (``attention.fwd``).
 
     The Pallas kernel carries a recompute-based custom_vjp, so it is legal
     under ``jax.grad`` and serves as the training kernel. Ragged-position or
     1-token calls resolve to blockwise/ref via capability predicates.
+
+    ``partition`` names the mesh axes of q/k/v's (B, S, H, D) dims. A
+    backend GSPMD cannot partition runs per shard under it on a
+    multi-device mesh; each shard needs whole sequences.
     """
     kernel = kernel if kernel is not None else DEFAULT_CONFIG
     feats = KernelFeatures(
@@ -87,20 +93,24 @@ def flash_attention(
         sliding_window=sliding_window is not None,
     )
     spec = registry.resolve_backend("attention.fwd", feats, kernel)
-    return spec.fn(
-        q, k, v, q_positions=q_positions, k_positions=k_positions,
+    call = functools.partial(
+        spec.fn, q_positions=q_positions, k_positions=k_positions,
         causal=causal, sliding_window=sliding_window,
         logit_softcap=logit_softcap, scale=scale,
         cfg=kernel)
+    if not spec.per_shard:
+        return call(q, k, v)
+    return registry.per_shard(call, partition, q, k, v, whole_dims=(1,))
 
 
 def paged_gather_kv(k_pool: jax.Array, v_pool: jax.Array,
                     pos_pool: jax.Array, page_tables: jax.Array,
                     scale_pool: Optional[jax.Array] = None):
-    """Materialize a paged pool into contiguous per-sequence (B, N*page, ...)
-    K/V + positions via an XLA gather — the portable reference path for
-    paged decode. Unmapped logical pages (table entry -1) gather physical
-    page 0 but their positions are forced to -1, so masking drops them.
+    """Materialize a head-major ``(P, Hkv, page, D)`` paged pool into
+    contiguous per-sequence ``(B, N*page, Hkv, D)`` K/V + positions via an
+    XLA gather — the portable reference path for paged decode. Unmapped
+    logical pages (table entry -1) gather physical page 0 but their
+    positions are forced to -1, so masking drops them.
 
     ``scale_pool`` (quantized pools: (P, page, 2) per-slot fp32 scales) is
     gathered through the same table and applied, so callers always receive
@@ -108,10 +118,10 @@ def paged_gather_kv(k_pool: jax.Array, v_pool: jax.Array,
     """
     tbl = jnp.asarray(page_tables, jnp.int32)  # (B, N)
     B, N = tbl.shape
-    P, page, Hkv, D = k_pool.shape
+    P, Hkv, page, D = k_pool.shape
     safe = jnp.maximum(tbl, 0)
-    k = k_pool[safe].reshape(B, N * page, Hkv, D)
-    v = v_pool[safe].reshape(B, N * page, Hkv, D)
+    k = jnp.swapaxes(k_pool[safe], 2, 3).reshape(B, N * page, Hkv, D)
+    v = jnp.swapaxes(v_pool[safe], 2, 3).reshape(B, N * page, Hkv, D)
     if scale_pool is not None:
         from repro.quantization import kv as kv_quant
 
@@ -123,7 +133,7 @@ def paged_gather_kv(k_pool: jax.Array, v_pool: jax.Array,
 
 def decode_attention(
     q: jax.Array,  # (B, S', Hq, D)
-    k: jax.Array,  # (B, T, Hkv, D) cache — or (P, page, Hkv, D) pool (paged)
+    k: jax.Array,  # (B, Hkv, T, D) cache — or (P, Hkv, page, D) pool (paged)
     v: jax.Array,
     *,
     q_positions,  # (B, S') or (S',) absolute positions of the new tokens
@@ -212,9 +222,8 @@ def wkv6_decode(r, k, v, w, u, state, *,
 
 def wkv6(r, k, v, w, u, state=None, *,
          kernel: Optional[KernelConfig] = None, needs_grad: bool = False):
-    """WKV6 core (``wkv6``). Pallas chunked kernel where available and
-    eligible (forward-only); chunked-jnp ref otherwise — availability is
-    decided at registry import time with the reason surfaced in errors."""
+    """WKV6 core (``wkv6``). Pallas chunked kernel where eligible
+    (forward-only); chunked-jnp ref otherwise."""
     kernel = kernel if kernel is not None else DEFAULT_CONFIG
     feats = KernelFeatures(
         platform=registry.current_platform(),

@@ -37,10 +37,11 @@ dispatch adds no per-call or per-trace overhead on hot paths.
 from __future__ import annotations
 
 import dataclasses
-import logging
+import math
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.config import ConfigBase, config_class
 
@@ -57,9 +58,6 @@ __all__ = [
     "clear_dispatch_cache",
     "dispatch_cache_stats",
 ]
-
-logger = logging.getLogger(__name__)
-
 
 # ---------------------------------------------------------------------------
 # The one kernel config every kernel-calling layer shares (tentpole API).
@@ -184,15 +182,13 @@ class KernelSpec:
     platforms: Tuple[str, ...] = ("*",)
     priority: int = 0
     supports: Optional[CapabilityPredicate] = None
-    # Import-time availability (satellite: wkv6 import failures are explicit
-    # and logged, never silently swallowed into a ref fallback).
-    available: bool = True
-    unavailable_reason: str = ""
+    # GSPMD cannot partition this impl (a Mosaic kernel call): on a
+    # multi-device mesh the dispatcher runs it per shard (``per_shard``),
+    # split as the calling layer's partition says.
+    per_shard: bool = False
 
     def rejection_reason(self, features: KernelFeatures) -> Optional[str]:
         """None if eligible for ``features``, else why not."""
-        if not self.available:
-            return f"unavailable at import time: {self.unavailable_reason}"
         if "*" not in self.platforms and features.platform not in self.platforms:
             return (f"requires platform in {list(self.platforms)} "
                     f"(running on {features.platform!r})")
@@ -217,9 +213,6 @@ def register(spec: KernelSpec) -> KernelSpec:
     extend the built-ins without editing this module."""
     _REGISTRY.setdefault(spec.op, {})[spec.backend] = spec
     _DISPATCH_CACHE.clear()
-    if not spec.available:
-        logger.warning("kernel %s/%s registered UNAVAILABLE: %s",
-                       spec.op, spec.backend, spec.unavailable_reason)
     return spec
 
 
@@ -239,8 +232,14 @@ def clear_dispatch_cache() -> None:
     _CACHE_STATS["hits"] = _CACHE_STATS["misses"] = 0
 
 
-def dispatch_cache_stats() -> Dict[str, int]:
-    return dict(_CACHE_STATS, size=len(_DISPATCH_CACHE))
+def dispatch_cache_stats() -> Dict[str, Any]:
+    """Hit/miss counts, the cache size, and under ``resolved`` every
+    memoized resolution as ``(op, requested backend, features, chosen
+    backend)`` — what each call site actually ran."""
+    return dict(_CACHE_STATS, size=len(_DISPATCH_CACHE),
+                resolved=[(op, requested, feats, spec.backend)
+                          for (op, requested, feats), spec
+                          in _DISPATCH_CACHE.items()])
 
 
 def _op_specs(op: str) -> Dict[str, KernelSpec]:
@@ -429,6 +428,40 @@ def _chain(*preds: CapabilityPredicate) -> CapabilityPredicate:
 # ---------------------------------------------------------------------------
 
 
+def per_shard(fn: Callable, partition, *arrays,
+              whole_dims: Tuple[int, ...] = ()):
+    """Runs ``fn(*arrays)`` on each device's shard of the ambient mesh.
+
+    ``partition`` (axis names per dim, as layers state their shardings)
+    splits every array and the result alike; ``fn`` sees one shard. Without
+    a mesh, or on one device, ``fn`` runs as is. A split that ``fn`` cannot
+    take raises: one of ``whole_dims``, or a dim whose size the devices do
+    not divide (silently replicating it would make every device compute the
+    whole call).
+    """
+    from repro.core.utils import current_mesh, resolve_spec
+
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn(*arrays)
+    if partition is None:
+        raise ValueError(
+            f"a kernel GSPMD cannot partition runs on a {mesh.size}-device "
+            "mesh: the caller must state its partition")
+    spec = resolve_spec(partition, mesh)
+    for x in arrays:
+        for i, (dim, entry) in enumerate(zip(x.shape, spec)):
+            names = entry if isinstance(entry, tuple) else (entry,)
+            n = math.prod(mesh.shape[a] for a in names if a is not None)
+            if n > 1 and (i in whole_dims or dim % n):
+                raise ValueError(
+                    f"partition {tuple(spec)} splits dim {i} of {x.shape} "
+                    f"over {n} devices; the kernel needs it "
+                    + ("whole" if i in whole_dims else "to divide"))
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(arrays),
+                         out_specs=spec, check_vma=False)(*arrays)
+
+
 def _register_builtin_specs() -> None:
     from repro.kernels import ref as _ref
     from repro.kernels.flash_attention import flash_attention as _flash_vjp
@@ -437,6 +470,7 @@ def _register_builtin_specs() -> None:
         paged_flash_decode_forward,
     )
     from repro.kernels.rmsnorm import rmsnorm_forward
+    from repro.kernels.wkv6 import wkv6_forward
 
     # ---- attention.fwd --------------------------------------------------
     # fn(q, k, v, *, q_positions, k_positions, causal, sliding_window,
@@ -472,11 +506,13 @@ def _register_builtin_specs() -> None:
     register(KernelSpec(
         op="attention.fwd", backend="pallas", fn=_fwd_pallas(False),
         platforms=("tpu",), priority=100,
-        supports=_chain(_pallas_gate, _flash_fwd_caps)))
+        supports=_chain(_pallas_gate, _flash_fwd_caps), per_shard=True))
+    # Interpreted, the kernel runs per shard too: CPU tests exercise the
+    # chip's multi-device path.
     register(KernelSpec(
         op="attention.fwd", backend="pallas:interpret", fn=_fwd_pallas(True),
         platforms=("*",), priority=90,
-        supports=_chain(_interpret_gate, _flash_fwd_caps)))
+        supports=_chain(_interpret_gate, _flash_fwd_caps), per_shard=True))
     register(KernelSpec(
         op="attention.fwd", backend="blockwise", fn=_fwd_blockwise,
         platforms=("*",), priority=50))
@@ -524,6 +560,11 @@ def _register_builtin_specs() -> None:
             k = k.astype(q.dtype)
             v = v.astype(q.dtype)
             logits_shard_fn = None
+        else:
+            # Head-major (B, Hkv, T, D) cache -> the oracle's (B, T, Hkv, D);
+            # XLA folds the transpose into the logits dot.
+            k = jnp.swapaxes(k, 1, 2)
+            v = jnp.swapaxes(v, 1, 2)
         return _ref.reference_attention(
             q, k, v, q_positions=q_positions, k_positions=k_positions,
             causal=causal, sliding_window=sliding_window,
@@ -571,19 +612,6 @@ def _register_builtin_specs() -> None:
 
     # ---- wkv6 -----------------------------------------------------------
     # fn(r, k, v, w, u, state, *, cfg)
-    # Availability is decided HERE, at import time, with the real reason
-    # logged and surfaced in resolution errors — the old ops.wkv6 wrapped
-    # its import in `except ImportError`, silently swallowing genuine
-    # failures *inside* kernels/wkv6.py into the slow ref path.
-
-    wkv6_forward = None
-    wkv6_reason = ""
-    try:
-        from repro.kernels.wkv6 import wkv6_forward as _wkv6_forward
-
-        wkv6_forward = _wkv6_forward
-    except ImportError as e:
-        wkv6_reason = f"{type(e).__name__}: {e}"
 
     def _wkv6_pallas(interpret):
         def fn(r, k, v, w, u, state, *, cfg):
@@ -610,17 +638,13 @@ def _register_builtin_specs() -> None:
 
     wkv_caps = _forward_only("wkv6 kernel")
     register(KernelSpec(
-        op="wkv6", backend="pallas",
-        fn=_wkv6_pallas(False) if wkv6_forward else None,
+        op="wkv6", backend="pallas", fn=_wkv6_pallas(False),
         platforms=("tpu",), priority=100,
-        supports=_chain(_pallas_gate, wkv_caps),
-        available=wkv6_forward is not None, unavailable_reason=wkv6_reason))
+        supports=_chain(_pallas_gate, wkv_caps)))
     register(KernelSpec(
-        op="wkv6", backend="pallas:interpret",
-        fn=_wkv6_pallas(True) if wkv6_forward else None,
+        op="wkv6", backend="pallas:interpret", fn=_wkv6_pallas(True),
         platforms=("*",), priority=90,
-        supports=_chain(_interpret_gate, wkv_caps),
-        available=wkv6_forward is not None, unavailable_reason=wkv6_reason))
+        supports=_chain(_interpret_gate, wkv_caps)))
     register(KernelSpec(
         op="wkv6", backend="ref", fn=_wkv6_ref,
         platforms=("*",), priority=0))

@@ -9,11 +9,16 @@ same carry pattern as the flash-attention kernel.
 Grid: (B * H, num_chunks) with the chunk axis sequential ("arbitrary").
 Per (b, h, chunk):
   logw        = log w (chunk, K)           decay logs
-  cum/cum_ex  = inclusive/exclusive prefix sums
+  cum/cum_ex  = inclusive/exclusive prefix sums (a lower-triangular ones
+                matmul at full f32 precision: Mosaic has no cumsum)
   o = (r * e^{cum_ex}) @ s                           state contribution
     + tril_strict((r e^{cum_ex - mid}) (k e^{mid - cum})^T) @ v   intra-chunk
     + ((r*u) . k) v                                   current-token bonus
   s = e^{total} * s + (k e^{total - cum})^T @ v       state update
+
+A sequence that is not a whole number of chunks is padded with identity
+steps (w = 1, k = v = r = 0), which leave the state and the real outputs
+unchanged.
 
 Forward only (training uses the chunked jnp form which autodiffs); decode
 uses the O(1) recurrent step. Validated in interpret mode vs
@@ -29,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 __all__ = ["wkv6_forward"]
 
@@ -50,10 +53,16 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_out_ref,
     u = u_ref[0].astype(jnp.float32)  # (1, K) -> broadcast
 
     logw = jnp.log(jnp.maximum(w, 1e-20))
-    cum = jnp.cumsum(logw, axis=0)  # inclusive
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (cols <= rows).astype(jnp.float32)
+    cum = jax.lax.dot_general(  # inclusive prefix sum over the chunk
+        tril, logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     cum_ex = cum - logw  # exclusive
-    total = cum[-1:]  # (1, K)
-    mid = cum[chunk // 2][None]  # (1, K) re-centering for fp32 range
+    total = cum[chunk - 1:]  # (1, K)
+    mid = cum[chunk // 2:chunk // 2 + 1]  # (1, K) re-centering for fp32 range
 
     s = s_scr[...]  # (K, V)
     # state contribution
@@ -65,8 +74,6 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_out_ref,
     kj = k * jnp.exp(mid - cum)
     att = jax.lax.dot_general(ri, kj, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (C, C)
-    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     att = jnp.where(cols < rows, att, 0.0)
     o = o + jax.lax.dot_general(att, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -97,15 +104,17 @@ def wkv6_forward(
     chunk_size: int = 64,
     interpret: bool = False,
 ):
-    B, T, H, K = r.shape
+    B, T_in, H, K = r.shape
     V = v.shape[-1]
     if state is None:
         state = jnp.zeros((B, H, K, V), jnp.float32)
-    if T % chunk_size != 0:
-        from repro.kernels import ref as _ref
-
-        return _ref.reference_wkv6(r, k, v, w, u, state, chunk_size=chunk_size)
     C = chunk_size
+    T = -(-T_in // C) * C
+    if T != T_in:
+        # Identity steps: decay 1 and zero k/v/r leave the state as it was.
+        pad = ((0, 0), (0, T - T_in), (0, 0), (0, 0))
+        r, k, v = (jnp.pad(x, pad) for x in (r, k, v))
+        w = jnp.pad(w, pad, constant_values=1)
     n_chunks = T // C
 
     # Head-major: (B*H, T, *); chunk index becomes the sequential grid dim.
@@ -147,11 +156,11 @@ def wkv6_forward(
             jax.ShapeDtypeStruct((B * H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(rh, kh, vh, wh, uh, sh)
 
-    out = out.reshape(B, H, T, V).transpose(0, 2, 1, 3)
+    out = out.reshape(B, H, T, V).transpose(0, 2, 1, 3)[:, :T_in]
     return out, s_out.reshape(B, H, K, V)

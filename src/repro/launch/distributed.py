@@ -197,7 +197,7 @@ def initialize(cfg) -> Optional[object]:
     if cfg.backend == "jax":
         import jax
 
-        if not getattr(jax.distributed, "is_initialized", lambda: False)():
+        if not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=cfg.coordinator_address or None,
                 num_processes=cfg.process_count,

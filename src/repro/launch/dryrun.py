@@ -159,7 +159,7 @@ def adapt_for_batch1_decode(model_cfg):
             hd = cfg.head_dim
             heads_ax = "model" if (nh and nh % MODEL_AXIS == 0) else None
             dim_ax = "model" if heads_ax is None and hd and hd % MODEL_AXIS == 0 else None
-            cfg.set(kv_cache_partition=(None, "data", heads_ax, dim_ax))
+            cfg.set(kv_cache_partition=(None, heads_ax, "data", dim_ax))
         if "state_partition" in cfg.keys():
             cfg.set(state_partition=_drop_batch_axes(cfg.state_partition))
 

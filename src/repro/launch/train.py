@@ -1,10 +1,12 @@
 """End-to-end training launcher: ``--arch`` selects any assigned architecture.
 
-Usage (CPU-scale by default — reduced model unless --full):
+Usage (reduced model unless --full):
   PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
-      --steps 200 --batch 8 --seq 64 --mesh 1x1
+      --steps 200 --batch 8 --seq 64
 
-Mesh rules pick per-instance-type settings exactly as the paper's App. A.
+Mesh rules pick per-instance-type settings exactly as the paper's App. A;
+the instance type defaults to the attached device (``tpu-v5e-1``,
+``tpu-v5e-4``, ``cpu-local``).
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ import sys
 import jax
 
 from repro.configs import registry
+from repro.core.compile_cache import configure_compile_cache
 from repro.core.config import config_for_function
 from repro.trainer import optimizers as opt_lib
 from repro.layers.base import bf16_policy
@@ -44,74 +47,46 @@ from repro.runtime.signals import Preempted, install_preemption_handler
 # anything left on "auto" resolves per-platform via the kernel registry's
 # capability predicates. Rules are anchored fullmatch: list specific
 # instance types before broad families.
-MESH_RULES = [
-    # Low-precision variants ride the same recipe plus ONE extra modifier:
-    # "-fp8" suffix = fp8 train compute (delayed-scaling fake-quant at
-    # module boundaries, fp32 masters kept by ZeRO-1 as usual); "-w8a8"
-    # suffix = int8 weight/activation GEMMs. Listed before the broad
-    # family rule (fullmatch, first match wins).
-    ("tpu-v5e-.*-fp8", [
+
+
+def _tpu_v5e_rule(*extra):
+    """The v5e recipe plus ``extra`` modifiers. The (data, model) mesh takes
+    every chip present on the data axis — (1, 1) on one chip, (4, 1) on a
+    2x2 host — so one rule fits any slice."""
+    return [
         MeshShapeModifier.default_config().set(
-            mesh_shape=(16, 16), mesh_axis_names=("data", "model")),
-        RematPolicyModifier.default_config().set(policy="full"),
-        KernelModifier.default_config().set(
-            op_overrides={"attention.fwd": "pallas"},
-            update={"block_q": 256, "block_k": 512}),
-        DtypePolicyModifier.default_config().set(policy=bf16_policy()),
-        Zero1Modifier.default_config(),
-        QuantizationModifier.default_config().set(fp8=True),
-    ]),
-    # Memory-frugal variants: same recipe plus ONE MemoryModifier (paper
-    # §4.2 applied to training memory). "-frugal" = bf16 Adam EMA buffers +
-    # reversible residual stacks (2x smaller moments, O(1)-in-depth
-    # activations); "-frugal-max" = Adafactor factored second moments +
-    # reversible (optimizer state drops from 8 bytes/param to O(n+m) per
-    # matrix). Both compose with the rule's ZeRO-1 + bf16 policy.
-    ("tpu-v5e-.*-frugal-max", [
-        MeshShapeModifier.default_config().set(
-            mesh_shape=(16, 16), mesh_axis_names=("data", "model")),
-        RematPolicyModifier.default_config().set(policy="full"),
-        KernelModifier.default_config().set(
-            op_overrides={"attention.fwd": "pallas"},
-            update={"block_q": 256, "block_k": 512}),
-        DtypePolicyModifier.default_config().set(policy=bf16_policy()),
-        Zero1Modifier.default_config(),
-        MemoryModifier.default_config().set(
-            optimizer="adafactor", reversible=True),
-    ]),
-    ("tpu-v5e-.*-frugal", [
-        MeshShapeModifier.default_config().set(
-            mesh_shape=(16, 16), mesh_axis_names=("data", "model")),
-        RematPolicyModifier.default_config().set(policy="full"),
-        KernelModifier.default_config().set(
-            op_overrides={"attention.fwd": "pallas"},
-            update={"block_q": 256, "block_k": 512}),
-        DtypePolicyModifier.default_config().set(policy=bf16_policy()),
-        Zero1Modifier.default_config(),
-        MemoryModifier.default_config().set(
-            state_dtype="bf16", reversible=True),
-    ]),
-    ("tpu-v5e-.*-w8a8", [
-        MeshShapeModifier.default_config().set(
-            mesh_shape=(16, 16), mesh_axis_names=("data", "model")),
-        RematPolicyModifier.default_config().set(policy="full"),
-        KernelModifier.default_config().set(
-            op_overrides={"attention.fwd": "pallas"},
-            update={"block_q": 256, "block_k": 512}),
-        DtypePolicyModifier.default_config().set(policy=bf16_policy()),
-        Zero1Modifier.default_config(),
-        QuantizationModifier.default_config().set(w8a8=True),
-    ]),
-    ("tpu-v5e-.*", [
-        MeshShapeModifier.default_config().set(
-            mesh_shape=(16, 16), mesh_axis_names=("data", "model")),
+            mesh_shape=(-1, 1), mesh_axis_names=("data", "model")),
         RematPolicyModifier.default_config().set(policy="full"),
         KernelModifier.default_config().set(
             op_overrides={"attention.fwd": "pallas"},
             update={"block_q": 256, "block_k": 512}),  # v5e tiling table
         DtypePolicyModifier.default_config().set(policy=bf16_policy()),
         Zero1Modifier.default_config(),
-    ]),
+        *extra,
+    ]
+
+
+MESH_RULES = [
+    # Low-precision variants ride the same recipe plus ONE extra modifier:
+    # "-fp8" suffix = fp8 train compute (delayed-scaling fake-quant at
+    # module boundaries, fp32 masters kept by ZeRO-1 as usual); "-w8a8"
+    # suffix = int8 weight/activation GEMMs.
+    ("tpu-v5e-.*-fp8", _tpu_v5e_rule(
+        QuantizationModifier.default_config().set(fp8=True))),
+    # Memory-frugal variants (paper §4.2 applied to training memory):
+    # "-frugal" = bf16 Adam EMA buffers + reversible residual stacks (2x
+    # smaller moments, O(1)-in-depth activations); "-frugal-max" = Adafactor
+    # factored second moments + reversible (optimizer state drops from 8
+    # bytes/param to O(n+m) per matrix).
+    ("tpu-v5e-.*-frugal-max", _tpu_v5e_rule(
+        MemoryModifier.default_config().set(
+            optimizer="adafactor", reversible=True))),
+    ("tpu-v5e-.*-frugal", _tpu_v5e_rule(
+        MemoryModifier.default_config().set(
+            state_dtype="bf16", reversible=True))),
+    ("tpu-v5e-.*-w8a8", _tpu_v5e_rule(
+        QuantizationModifier.default_config().set(w8a8=True))),
+    ("tpu-v5e-.*", _tpu_v5e_rule()),
     ("cpu-.*", [
         MeshShapeModifier.default_config().set(
             mesh_shape=(1,), mesh_axis_names=("data",)),
@@ -120,6 +95,24 @@ MESH_RULES = [
             op_overrides={"attention.fwd": "ref"}),
     ]),
 ]
+
+# Instance family of each attached device, keyed by ``device_kind`` as JAX
+# reports it.
+_TPU_FAMILIES = {"TPU v5 lite": "tpu-v5e"}
+
+
+def attached_instance_type() -> str:
+    """The instance type of the devices this process sees, e.g.
+    ``tpu-v5e-4`` for a 2x2 v5e host or ``cpu-local``. An accelerator with
+    no mesh rule is an error, never a silent CPU recipe."""
+    devices = jax.devices()
+    if devices[0].platform == "cpu":
+        return "cpu-local"
+    kind = devices[0].device_kind
+    if kind not in _TPU_FAMILIES:
+        raise ValueError(f"no mesh rule for device kind {kind!r} "
+                         f"(known: {sorted(_TPU_FAMILIES)})")
+    return f"{_TPU_FAMILIES[kind]}-{len(devices)}"
 
 
 def build_trainer_config(arch: str, *, full: bool, steps: int, batch: int,
@@ -156,14 +149,20 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--instance-type", default="cpu-local")
+    ap.add_argument("--instance-type", default="auto",
+                    help="mesh-rule key; 'auto' names the attached device "
+                         "(e.g. tpu-v5e-1, cpu-local)")
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--out", default="")
     args = ap.parse_args()
 
+    configure_compile_cache()
+    instance_type = args.instance_type
+    if instance_type == "auto":
+        instance_type = attached_instance_type()
     cfg = build_trainer_config(
         args.arch, full=args.full, steps=args.steps, batch=args.batch,
-        seq=args.seq, lr=args.lr, instance_type=args.instance_type,
+        seq=args.seq, lr=args.lr, instance_type=instance_type,
         checkpoint_dir=args.checkpoint_dir)
     trainer = cfg.instantiate()
     # Preemption wiring (§5): a scheduler SIGTERM sets the event; the loop

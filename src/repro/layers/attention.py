@@ -63,16 +63,15 @@ class MultiheadAttention(BaseLayer):
         # resolves them to "ref" (whose logits_shard_fn keeps GSPMD in the
         # partial-softmax layout) and explicit "pallas" rejects with reason.
         kernel: KernelConfig = KernelConfig()
-        # KV cache layout: "dense" (per-slot (B, T, Hkv, D) ring buffer) |
-        # "paged" (shared pool of fixed-size pages + per-sequence page
-        # tables, vLLM-style). Paged allocates KV on demand instead of
+        # KV cache layout: "dense" (per-slot (B, Hkv, T, D) ring buffer) |
+        # "paged" (shared (P, Hkv, page, D) pool of fixed-size pages +
+        # per-sequence page tables, vLLM-style). Paged allocates KV on demand instead of
         # slots x max_len up front — the serving subsystem
         # (repro.serving) packs more concurrent sequences into the same
         # memory and evicts/restores them page-wise. Config choice, not a
         # code change (paper §4.2): engines only see opaque state pytrees.
         kv_cache_layout: str = "dense"
-        # Tokens per physical page. On real TPUs use a multiple of the
-        # sublane count (8 f32 / 16 bf16) for efficient pool tiling.
+        # Tokens per physical page.
         page_size: int = 16
         # Physical pages in the shared pool (page 0 is reserved as the null
         # target of unmapped table entries and is never written). None ->
@@ -88,8 +87,9 @@ class MultiheadAttention(BaseLayer):
         out_weight_partition: PartitionSpecLike = ("model", "data")
         # Activation sharding for (B, S, H*D) projections.
         hidden_partition: PartitionSpecLike = (("pod", "data"), None, "model")
-        # KV cache sharding (B, T, Hkv, D).
-        kv_cache_partition: PartitionSpecLike = (("pod", "data"), None, "model", None)
+        # KV cache sharding, head-major (B, Hkv, T, D): the decode kernel's
+        # K/V tile is then a (tokens, head_dim) slab of the cache itself.
+        kv_cache_partition: PartitionSpecLike = (("pod", "data"), "model", None, None)
         kv_cache_dtype: Any = jnp.bfloat16
 
     def __init__(self, cfg, *, parent=None):
@@ -205,16 +205,19 @@ class MultiheadAttention(BaseLayer):
             if page_tables is None and cfg.kv_cache_partition is not None:
                 kv_spec = tuple(cfg.kv_cache_partition)
                 # logits (B, Hkv, G, S', T): batch + cache-seq axes from config.
-                spec = (kv_spec[0], None, None, None, kv_spec[1])
+                spec = (kv_spec[0], None, None, None, kv_spec[2])
                 logits_shard_fn = lambda l: self._shard(l, spec)  # noqa: E731
             return kernel_ops.decode_attention(
                 q, k, v, page_tables=page_tables, scale_pool=scale_pool,
                 replicated_cache=self._kv_cache_replicated(),
                 logits_shard_fn=logits_shard_fn,
                 kernel=self.kernel_config, **kwargs)
+        # (B, S, H, D) sharding: hidden_partition's H*D split falls on heads.
+        hp = cfg.hidden_partition
+        partition = (*hp, None) if hp is not None else (None,) * 4
         return kernel_ops.flash_attention(
             q, k, v, kernel=self.kernel_config, needs_grad=self.is_training,
-            **kwargs)
+            partition=partition, **kwargs)
 
     # --------------------------------------------------------------- forward
 
@@ -255,19 +258,19 @@ class MultiheadAttention(BaseLayer):
         cfg = self.config
         kv = tuple(cfg.kv_cache_partition) if cfg.kv_cache_partition else (None,) * 4
         if cfg.kv_cache_layout == "paged":
-            pool = (None, None, kv[2], kv[3])  # (P, page, Hkv, D)
+            pool = (None, kv[1], None, kv[3])  # (P, Hkv, page, D)
             specs = {"k_pool": pool, "v_pool": pool, "pos_pool": (None, None),
                      "page_table": (kv[0], None), "index": (kv[0],)}
             if self._kv_fmt is not None:
                 specs["scale_pool"] = (None, None, None)  # (P, page, 2)
             return specs
-        return {"k": kv, "v": kv, "pos": (kv[0], kv[1]), "index": (kv[0],)}
+        return {"k": kv, "v": kv, "pos": (kv[0], kv[2]), "index": (kv[0],)}
 
     def init_states(self, batch_size: int, max_len: int) -> Dict[str, Any]:
         """Empty KV cache. ``pos`` tracks the absolute position in each slot
         (-1 = invalid), which makes ring-buffer masking trivial.
 
-        Paged layout: a shared ``(num_pages, page_size, Hkv, D)`` pool, a
+        Paged layout: a shared ``(num_pages, Hkv, page_size, D)`` pool, a
         per-page position pool, and per-sequence page tables. Page 0 is the
         reserved null page (unmapped table entries clamp to it on reads and
         are masked; writes through unmapped entries are dropped). When the
@@ -279,11 +282,11 @@ class MultiheadAttention(BaseLayer):
         cfg = self.config
         if cfg.kv_cache_layout == "paged":
             page, n_logical, P = self._paged_geometry(batch_size, max_len)
-            pool_shape = (P, page, cfg.num_kv_heads, cfg.head_dim)
+            pool_shape = (P, cfg.num_kv_heads, page, cfg.head_dim)
             pool_spec = None
             if cfg.kv_cache_partition is not None:
                 kv = tuple(cfg.kv_cache_partition)
-                pool_spec = (None, None, kv[2], kv[3])
+                pool_spec = (None, kv[1], None, kv[3])
             if P >= 1 + batch_size * n_logical:
                 table = 1 + jnp.arange(batch_size * n_logical, dtype=jnp.int32
                                        ).reshape(batch_size, n_logical)
@@ -304,7 +307,7 @@ class MultiheadAttention(BaseLayer):
                 state["scale_pool"] = kv_quant.init_scale_pool(P, page)
             return state
         T = self._cache_len(max_len)
-        shape = (batch_size, T, cfg.num_kv_heads, cfg.head_dim)
+        shape = (batch_size, cfg.num_kv_heads, T, cfg.head_dim)
         cache = {
             "k": jnp.zeros(shape, cfg.kv_cache_dtype),
             "v": jnp.zeros(shape, cfg.kv_cache_dtype),
@@ -334,10 +337,10 @@ class MultiheadAttention(BaseLayer):
         valid = valid & (positions >= 0) & (positions < table.shape[1] * page)
         logical = jnp.clip(positions // page, 0, table.shape[1] - 1)
         phys = jnp.take_along_axis(table, logical, axis=1)  # (B, S)
-        flat = phys * page + positions % page
+        phys = jnp.where(valid & (phys > 0), phys, P)  # page 0 = null
+        slot = positions % page
+        flat = phys * page + slot
         oob = P * page
-        flat = jnp.where(valid & (phys > 0), flat, oob)  # page 0 = null
-        H, D = cfg.num_kv_heads, cfg.head_dim
         if self._kv_fmt is not None:
             # Quantize-on-write: per-token-slot scales scatter through the
             # same (OOB-dropping) flat index as the payload, so a dropped
@@ -350,16 +353,16 @@ class MultiheadAttention(BaseLayer):
             k_st = k.astype(cfg.kv_cache_dtype)
             v_st = v.astype(cfg.kv_cache_dtype)
             scales = None
-        new_k = state["k_pool"].reshape(oob, H, D).at[flat].set(
-            k_st).reshape(P, page, H, D)
-        new_v = state["v_pool"].reshape(oob, H, D).at[flat].set(
-            v_st).reshape(P, page, H, D)
+        # Head-major pools: index (page, :, slot) gathers the (B, S) token
+        # rows of every head, matching the (B, S, Hkv, D) update.
+        new_k = state["k_pool"].at[phys, :, slot].set(k_st, mode="drop")
+        new_v = state["v_pool"].at[phys, :, slot].set(v_st, mode="drop")
         new_pos = state["pos_pool"].reshape(oob).at[flat].set(
-            positions.astype(jnp.int32)).reshape(P, page)
+            positions.astype(jnp.int32), mode="drop").reshape(P, page)
         pools = {"k_pool": new_k, "v_pool": new_v, "pos_pool": new_pos}
         if scales is not None:
             pools["scale_pool"] = state["scale_pool"].reshape(oob, 2).at[
-                flat].set(scales).reshape(P, page, 2)
+                flat].set(scales, mode="drop").reshape(P, page, 2)
         return pools
 
     def prefill(self, state: Dict[str, Any], x: jax.Array,
@@ -391,7 +394,7 @@ class MultiheadAttention(BaseLayer):
                                         valid=pos_b < length)
             return {**pools, "page_table": state["page_table"],
                     "index": jnp.broadcast_to(length, (B,))}, y
-        T = state["k"].shape[1]
+        T = state["k"].shape[2]
         if S > T:
             # Ring layout: keep the last T *valid* tokens.
             start = jnp.clip(length - T, 0, S - T)
@@ -404,8 +407,10 @@ class MultiheadAttention(BaseLayer):
         # Invalid tokens scatter to index T (out of bounds -> dropped), so
         # bucket padding never overwrites live ring slots.
         slots = jnp.where(valid, p_keep % T, T)
-        new_k = state["k"].at[:, slots].set(k_keep.astype(cfg.kv_cache_dtype))
-        new_v = state["v"].at[:, slots].set(v_keep.astype(cfg.kv_cache_dtype))
+        new_k = state["k"].at[:, :, slots].set(
+            jnp.swapaxes(k_keep, 1, 2).astype(cfg.kv_cache_dtype))
+        new_v = state["v"].at[:, :, slots].set(
+            jnp.swapaxes(v_keep, 1, 2).astype(cfg.kv_cache_dtype))
         new_pos = state["pos"].at[:, slots].set(p_keep.astype(jnp.int32)[None, :])
         new_state = {
             "k": self._shard(new_k, cfg.kv_cache_partition),
@@ -441,11 +446,12 @@ class MultiheadAttention(BaseLayer):
             return {**pools, "page_table": state["page_table"],
                     "index": index + S_new}, self.o_proj(out)
 
-        T = state["k"].shape[1]
+        T = state["k"].shape[2]
         slots = positions % T  # (B, S')
         rows = jnp.arange(B)[:, None]
-        new_k = state["k"].at[rows, slots].set(k.astype(cfg.kv_cache_dtype))
-        new_v = state["v"].at[rows, slots].set(v.astype(cfg.kv_cache_dtype))
+        # (rows, :, slots) indexes (B, S') token rows of every head.
+        new_k = state["k"].at[rows, :, slots].set(k.astype(cfg.kv_cache_dtype))
+        new_v = state["v"].at[rows, :, slots].set(v.astype(cfg.kv_cache_dtype))
         new_pos = state["pos"].at[rows, slots].set(positions.astype(jnp.int32))
 
         out = self._attend(

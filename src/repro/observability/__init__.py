@@ -22,11 +22,12 @@ merged into one fleet timeline, step-boundary straggler skew).
 """
 
 from repro.observability.hardware import (
-    PEAK_FLOPS_PER_DEVICE,
+    PEAK_FLOPS_BY_DEVICE_KIND,
     ProfilerWindow,
     compiled_cost,
     device_memory_stats,
     estimate_mfu,
+    device_peak_flops,
 )
 from repro.observability.metrics import (
     Counter,
@@ -49,7 +50,7 @@ from repro.observability.tracing import (
 )
 
 __all__ = [
-    "PEAK_FLOPS_PER_DEVICE",
+    "PEAK_FLOPS_BY_DEVICE_KIND",
     "Counter",
     "Gauge",
     "Histogram",
@@ -64,6 +65,7 @@ __all__ = [
     "compiled_cost",
     "device_memory_stats",
     "estimate_mfu",
+    "device_peak_flops",
     "load_trace",
     "merge_traces",
     "validate_chrome_trace",

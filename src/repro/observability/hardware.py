@@ -5,91 +5,87 @@ MFU (model FLOPs utilization) is THE cross-hardware efficiency number
 (Modalities/PaLM convention): achieved model FLOP/s over the chip's peak.
 The numerator comes from the *compiled* train step's own
 ``cost_analysis()`` — what XLA will actually execute, including remat
-recompute — so it needs no analytical per-arch FLOP formula and stays
-correct under kernel/remat/dtype changes. The denominator is a
-per-platform peak table, overridable per run (``peak_flops_per_device``)
-because "the" peak depends on dtype and part number.
-
-On this CPU container the absolute MFU is not meaningful as a hardware
-number, but the plumbing (compiled-cost → per-step gauge → BENCH_train
-column) is exactly what runs on an accelerator, and relative movement
-still tracks regressions.
+recompute — so it needs no analytical per-arch FLOP formula. The
+denominator is a peak table keyed by ``device_kind`` (overridable per run,
+``peak_flops_per_device``, because "the" peak depends on dtype). A device
+that is not in the table is an error, and the CPU has no peak: MFU there is
+``None``, never a number.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Any, Dict, Optional
+from typing import Dict, Optional
 
 import jax
 
 __all__ = [
-    "PEAK_FLOPS_PER_DEVICE",
+    "PEAK_FLOPS_BY_DEVICE_KIND",
     "compiled_cost",
     "device_memory_stats",
     "estimate_mfu",
-    "peak_flops_for_platform",
+    "device_peak_flops",
     "ProfilerWindow",
 ]
 
-# Representative bf16 peak FLOP/s per device. TPU matches the roofline
-# constant the dry-run analysis already uses (v5e 197 TFLOP/s); GPU is an
-# A100-class bf16 peak; CPU is a nominal AVX-class figure so the MFU
-# column exists (and tracks relative changes) off-accelerator.
-PEAK_FLOPS_PER_DEVICE: Dict[str, float] = {
-    "tpu": 197e12,
-    "gpu": 312e12,
-    "cpu": 1e11,
+# Dense bf16 peak FLOP/s of one chip, keyed by ``device.device_kind``.
+# Source: Google Cloud TPU documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 394 TOP/s int8, 16 GB HBM at 819 GB/s per chip).
+PEAK_FLOPS_BY_DEVICE_KIND: Dict[str, float] = {
+    "TPU v5 lite": 197e12,
 }
 
 
-def peak_flops_for_platform(platform: Optional[str] = None) -> float:
-    platform = platform or jax.default_backend()
-    return PEAK_FLOPS_PER_DEVICE.get(platform, PEAK_FLOPS_PER_DEVICE["cpu"])
+def device_peak_flops(device=None) -> Optional[float]:
+    """The table's peak for ``device`` (default: the first device); None on
+    the CPU, which has no device peak. Raises for an accelerator that is
+    not in the table."""
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    try:
+        return PEAK_FLOPS_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s for device kind {device.device_kind!r}; add it "
+            f"to PEAK_FLOPS_BY_DEVICE_KIND with its source (known: "
+            f"{sorted(PEAK_FLOPS_BY_DEVICE_KIND)})") from None
 
 
 def compiled_cost(compiled) -> Dict[str, Optional[float]]:
     """FLOPs + bytes-accessed of a compiled executable via XLA's own cost
-    analysis (``None`` fields when the backend doesn't report them).
-    ``cost_analysis()`` returns a dict on some backends and a one-element
-    list of dicts on others; both are handled."""
-    flops = bytes_accessed = None
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if ca:
-            flops = float(ca.get("flops", 0.0)) or None
-            bytes_accessed = float(ca.get("bytes accessed", 0.0)) or None
-    except Exception:  # noqa: BLE001 — backend without cost analysis
-        pass
-    return {"flops": flops, "bytes_accessed": bytes_accessed}
+    analysis (``None`` fields when a count is zero)."""
+    ca = compiled.cost_analysis()
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0]
+    return {"flops": float(ca.get("flops", 0.0)) or None,
+            "bytes_accessed": float(ca.get("bytes accessed", 0.0)) or None}
 
 
 def estimate_mfu(flops_per_step: Optional[float], step_time_s: float, *,
-                 num_devices: int = 1, platform: Optional[str] = None,
+                 num_devices: int = 1, device=None,
                  peak_flops_per_device: float = 0.0) -> Optional[float]:
-    """Achieved model FLOP/s over aggregate peak; None when unmeasurable.
+    """Achieved model FLOP/s over aggregate peak. None when unmeasurable:
+    no FLOPs, no step time, or a CPU ``device`` (default: the first
+    device).
 
     ``flops_per_step`` is the GLOBAL compiled-step FLOPs (XLA reports the
     whole SPMD program); the denominator scales by ``num_devices``.
     """
     if not flops_per_step or step_time_s <= 0:
         return None
-    peak = peak_flops_per_device or peak_flops_for_platform(platform)
-    if peak <= 0:
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
         return None
+    peak = peak_flops_per_device or device_peak_flops(device)
     return flops_per_step / (step_time_s * peak * max(num_devices, 1))
 
 
 def device_memory_stats(device=None) -> Dict[str, float]:
-    """Per-device memory stats (peak HBM in ``peak_bytes_in_use`` on
-    TPU/GPU). Empty dict on backends without memory stats (CPU)."""
+    """Per-device memory stats (peak HBM in ``peak_bytes_in_use`` on TPU).
+    Empty dict on backends without memory stats (CPU)."""
     device = device or jax.local_devices()[0]
-    try:
-        stats = device.memory_stats()
-    except Exception:  # noqa: BLE001
-        stats = None
+    stats = device.memory_stats()
     if not stats:
         return {}
     return {k: float(v) for k, v in stats.items()

@@ -35,8 +35,8 @@ class ObservabilityConfig(ConfigBase):
     ``mfu``            — compute compiled-step FLOPs once and gauge per-step
                          MFU (costs one extra lower+compile, off the step
                          path).
-    ``peak_flops_per_device`` — MFU denominator override (0 = per-platform
-                         default table).
+    ``peak_flops_per_device`` — MFU denominator override (0 = the peak
+                         table keyed by ``device_kind``).
     ``reservoir_size`` — histogram reservoir bound.
     """
 

@@ -311,7 +311,10 @@ class FleetSupervisor:
 
         coord = os.path.join(self.workdir, f"coord{attempt}")
         os.makedirs(coord, exist_ok=True)
-        env = dict(os.environ)
+        # The file-backed fleet is a CPU substrate: its workers never touch
+        # an accelerator, so on a chip host they cannot contend with the
+        # process that holds the chip.
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         # repro may be a namespace package (__file__ is None) — resolve the
         # import root from __path__ so workers see the same tree we do.
         src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))

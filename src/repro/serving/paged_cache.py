@@ -44,7 +44,7 @@ import numpy as np
 __all__ = ["BlockAllocator", "PagedCacheManager", "PrefixIndex"]
 
 # Page axis of a pool leaf, keyed by leaf name, expressed as trailing rank:
-# k_pool/v_pool are (..., P, page, Hkv, D) -> page axis at ndim-4;
+# k_pool/v_pool are (..., P, Hkv, page, D) -> page axis at ndim-4;
 # pos_pool is (..., P, page) -> ndim-2; scale_pool (quantized KV: one fp32
 # scale per token slot per k|v) is (..., P, page, 2) -> ndim-3. Because the
 # scale rows share the physical-page axis, every page op below — COW

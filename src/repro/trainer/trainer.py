@@ -25,6 +25,7 @@ from repro.runtime.signals import Preempted
 from repro.core.config import REQUIRED, ConfigBase, Required, config_class
 from repro.core.module import Module, no_context
 from repro.core.utils import (
+    infer_mesh_shape,
     make_mesh,
     named_sharding,
     resolve_spec,
@@ -106,6 +107,7 @@ class SpmdTrainer(Module):
         input: SyntheticInput.Config = SyntheticInput.Config()
         checkpointer: Optional[Checkpointer.Config] = None
         # --- parallelism is configuration (paper §4.2) ---
+        # One entry may be -1: the devices the other axes leave over.
         mesh_shape: Tuple[int, ...] = (1,)
         mesh_axis_names: Tuple[str, ...] = ("data",)
         batch_partition: Any = (("pod", "data"),)  # applied to dim 0 of inputs
@@ -183,12 +185,14 @@ class SpmdTrainer(Module):
     def build_mesh(self):
         cfg = self.config
         if self._mesh is None:
-            n = int(np.prod(cfg.mesh_shape))
+            shape = infer_mesh_shape(cfg.mesh_shape, len(jax.devices()))
+            n = int(np.prod(shape))
             if n > len(jax.devices()):
                 raise RuntimeError(
                     f"mesh {cfg.mesh_shape} needs {n} devices, "
                     f"have {len(jax.devices())}")
-            self._mesh = make_mesh(cfg.mesh_shape, cfg.mesh_axis_names)
+            self._mesh = make_mesh(shape, cfg.mesh_axis_names,
+                                   devices=jax.devices()[:n])
         return self._mesh
 
     @no_context
@@ -431,9 +435,10 @@ class SpmdTrainer(Module):
         (argument + temp + output bytes of the executable).
 
         Memoized per trainer; the one extra lower+compile happens off the
-        step path (first logging step, or on demand from the bench).
-        Returns ``{}`` before the step is built and for the elastic
-        multi-process step (not a single jitted program).
+        step path (first logging step, or on demand from the bench). A
+        failing compile or analysis raises. Returns ``{}`` before the step
+        is built and for the elastic multi-process step (not a single
+        jitted program).
         """
         if self._step_cost is not None:
             return self._step_cost
@@ -441,19 +446,12 @@ class SpmdTrainer(Module):
                 or self._lower_shapes is None):
             return {}
         state_shapes, batch_abs = self._lower_shapes
-        try:
-            compiled = self._jit_step.lower(state_shapes, batch_abs).compile()
-        except Exception:  # noqa: BLE001 — backend without AOT lowering
-            self._step_cost = {}
-            return self._step_cost
+        compiled = self._jit_step.lower(state_shapes, batch_abs).compile()
         cost = compiled_cost(compiled)
-        try:
-            ma = compiled.memory_analysis()
-            cost["peak_hbm_proxy_bytes"] = int(
-                ma.argument_size_in_bytes + ma.temp_size_in_bytes
-                + ma.output_size_in_bytes)
-        except Exception:  # noqa: BLE001 — backend without memory_analysis
-            cost["peak_hbm_proxy_bytes"] = None
+        ma = compiled.memory_analysis()
+        cost["peak_hbm_proxy_bytes"] = int(
+            ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes)
         self._step_cost = cost
         return cost
 
@@ -699,7 +697,7 @@ class SpmdTrainer(Module):
             registry.gauge(name).set(v)
         if step_dur and step_dur > 0:
             registry.histogram("train/step_time_s").record(step_dur)
-            n_dev = int(np.prod(self.config.mesh_shape))
+            n_dev = self.build_mesh().size
             if tokens_per_step:
                 registry.gauge("train/tokens_per_s").set(
                     tokens_per_step / step_dur)

@@ -334,3 +334,18 @@ def test_fsdp_modifier_shards_params_on_multidevice_mesh():
                           timeout=600)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "OK ratio=" in proc.stdout
+
+
+def test_fleet_workers_are_pinned_to_cpu(tmp_path, monkeypatch):
+    """The file-backed fleet is a CPU substrate: every worker it spawns gets
+    JAX_PLATFORMS=cpu, so on a chip host no child contends for the chip
+    that the parent process holds."""
+    from repro.runtime import supervisor
+
+    spawned = []
+    monkeypatch.setattr(supervisor.subprocess, "Popen",
+                        lambda argv, env, **kw: spawned.append(env))
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    sup = supervisor.FleetSupervisor(str(tmp_path), schedule=(2,), steps=1)
+    sup._spawn(0, 2, None)
+    assert [env["JAX_PLATFORMS"] for env in spawned] == ["cpu", "cpu"]

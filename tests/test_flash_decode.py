@@ -30,9 +30,15 @@ def _mk_qkv(key, B, Sq, T, Hq, Hkv, D, dtype=jnp.float32):
     return q, k, v
 
 
+def _hm(cache):
+    """(B, T, Hkv, D) oracle layout -> the head-major (B, Hkv, T, D) cache."""
+    return jnp.swapaxes(cache, 1, 2)
+
+
 def _check_parity(q, k, v, q_pos, k_pos, **kw):
     out = ops.decode_attention(
-        q, k, v, q_positions=q_pos, k_positions=k_pos, kernel=INTERP, **kw)
+        q, _hm(k), _hm(v), q_positions=q_pos, k_positions=k_pos,
+        kernel=INTERP, **kw)
     expect = ref.reference_attention(
         q, k, v, q_positions=q_pos, k_positions=k_pos, **kw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -60,6 +66,25 @@ def test_flash_decode_multi_step_causal():
     k_pos = jnp.broadcast_to(jnp.arange(T), (B, T))
     q_pos = jnp.asarray([[13, 14, 15]])
     _check_parity(q, k, v, q_pos, k_pos)
+
+
+@pytest.mark.parametrize("T", [256, 300])
+def test_flash_decode_multi_block_cache(T):
+    """A cache longer than one KV tile streams in lane-aligned blocks; a
+    length that is not a whole number of blocks is never padded — the last
+    block's tail is masked in the kernel."""
+    from repro.kernels.flash_decode import flash_decode_forward
+
+    B, D = 2, 16
+    q, k, v = _mk_qkv(jax.random.PRNGKey(7), B, 1, T, 4, 2, D)
+    k_pos = jnp.broadcast_to(jnp.arange(T), (B, T))
+    q_pos = jnp.asarray([[T], [T // 2]])
+    out = flash_decode_forward(q, _hm(k), _hm(v), q_pos, k_pos, block_k=128,
+                               interpret=True)
+    expect = ref.reference_attention(q, k, v, q_positions=q_pos,
+                                     k_positions=k_pos)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_flash_decode_ring_wraparound():
@@ -98,8 +123,8 @@ def test_flash_decode_partial_and_empty_slots():
     valid = jnp.asarray([0, 1, 2, 3] + [-1] * (T - 4))
     k_pos = jnp.stack([valid, jnp.full((T,), -1)])  # row 1: empty slot
     q_pos = jnp.asarray([[4], [0]])
-    out = ops.decode_attention(q, k, v, q_positions=q_pos, k_positions=k_pos,
-                               kernel=INTERP)
+    out = ops.decode_attention(q, _hm(k), _hm(v), q_positions=q_pos,
+                               k_positions=k_pos, kernel=INTERP)
     expect = ref.reference_attention(q, k, v, q_positions=q_pos,
                                      k_positions=k_pos)
     # Row 0 has valid keys: exact parity with the reference oracle.
@@ -116,8 +141,8 @@ def test_flash_decode_bf16_inputs():
     q, k, v = _mk_qkv(jax.random.PRNGKey(6), B, 1, T, 4, 2, D, jnp.bfloat16)
     k_pos = jnp.broadcast_to(jnp.arange(T), (B, T))
     q_pos = jnp.full((B, 1), T)
-    out = ops.decode_attention(q, k, v, q_positions=q_pos, k_positions=k_pos,
-                               kernel=INTERP)
+    out = ops.decode_attention(q, _hm(k), _hm(v), q_positions=q_pos,
+                               k_positions=k_pos, kernel=INTERP)
     expect = ref.reference_attention(q, k, v, q_positions=q_pos,
                                      k_positions=k_pos)
     assert out.dtype == jnp.bfloat16

@@ -260,6 +260,7 @@ def _mk_wkv(key, B, T, H, K, V):
     (1, 32, 1, 8, 8, 8),
     (2, 64, 2, 16, 16, 16),
     (1, 128, 2, 32, 32, 32),
+    (2, 45, 2, 16, 16, 16),  # ragged: padded with identity steps
 ])
 def test_wkv6_kernel_matches_recurrent_oracle(B, T, H, K, V, chunk):
     from repro.kernels.wkv6 import wkv6_forward
@@ -288,13 +289,18 @@ def test_wkv6_kernel_with_initial_state():
 
 
 def test_wkv6_ragged_falls_back_to_ref():
+    """A ragged length stays on the kernel (padded with identity steps):
+    outputs and the final state match the recurrence."""
     from repro.kernels import ops
 
     B, T, H, K, V = 1, 30, 1, 8, 8  # T not divisible by chunk
     r, k, v, w, u = _mk_wkv(jax.random.PRNGKey(13), B, T, H, K, V)
-    out, s = ops.wkv6(r, k, v, w, u,
-                      kernel=KernelConfig().set(wkv_chunk_size=8,
-                                                interpret=True))
-    expect, _ = ref.reference_wkv6_recurrent(r, k, v, w, u)
+    cfg = KernelConfig().set(wkv_chunk_size=8, interpret=True)
+    assert ops.registry.resolve_backend(
+        "wkv6", ops.KernelFeatures(), cfg).backend == "pallas:interpret"
+    out, s = ops.wkv6(r, k, v, w, u, kernel=cfg)
+    expect, s_ref = ref.reference_wkv6_recurrent(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(s_ref),
                                rtol=2e-4, atol=2e-4)

@@ -142,7 +142,8 @@ def test_sliding_window_cache_is_bounded():
     layer = cfg.instantiate()
     state = layer.initialize_parameters_recursively(jax.random.PRNGKey(0))
     cache, _ = functional(layer, state=state, inputs=(1, 64), method="init_states")
-    assert cache["k"].shape[1] == 4, "SWA cache must be window-sized (long_500k enabler)"
+    # Head-major (B, Hkv, T, D): the slot axis is 2.
+    assert cache["k"].shape[2] == 4, "SWA cache must be window-sized (long_500k enabler)"
 
 
 def _tiny_layer_cfg(dim=32, moe=False):

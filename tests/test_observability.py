@@ -3,6 +3,7 @@ instrumentation, zero-retrace + overhead budgets, fleet trace merge."""
 
 import json
 import os
+import types
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from repro.observability import (
     ProfilerWindow,
     Tracer,
     compiled_cost,
+    device_peak_flops,
     estimate_mfu,
     load_trace,
     merge_traces,
@@ -145,19 +147,47 @@ def test_merge_traces_keeps_per_rank_lanes(tmp_path):
 # ------------------------------- hardware ------------------------------------
 
 
+V5E = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+
+
 def test_compiled_cost_and_mfu():
     fn = jax.jit(lambda x: (x @ x).sum())
     compiled = fn.lower(jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
     cost = compiled_cost(compiled)
     # 64^3 multiply-adds: XLA reports ~2*64^3 flops.
     assert cost["flops"] and cost["flops"] >= 2 * 64**3 * 0.5
-    mfu = estimate_mfu(cost["flops"], 1e-3, peak_flops_per_device=1e9)
+    mfu = estimate_mfu(cost["flops"], 1e-3, device=V5E,
+                       peak_flops_per_device=1e9)
     assert mfu == pytest.approx(cost["flops"] / 1e-3 / 1e9)
-    assert estimate_mfu(None, 1e-3) is None
-    assert estimate_mfu(1e6, 0.0) is None
+    assert estimate_mfu(None, 1e-3, device=V5E) is None
+    assert estimate_mfu(1e6, 0.0, device=V5E) is None
     # The denominator scales with device count.
-    assert estimate_mfu(1e6, 1.0, num_devices=2, peak_flops_per_device=1e6
-                        ) == pytest.approx(0.5)
+    assert estimate_mfu(1e6, 1.0, num_devices=2, device=V5E,
+                        peak_flops_per_device=1e6) == pytest.approx(0.5)
+    # The CPU has no device peak: no MFU, even with an explicit peak.
+    assert estimate_mfu(cost["flops"], 1e-3) is None
+    assert estimate_mfu(cost["flops"], 1e-3, peak_flops_per_device=1e9) is None
+
+
+@pytest.mark.parametrize("kind,expect", [
+    ("TPU v5 lite", 197e12),
+    ("TPU v9 imaginary", ValueError),
+    ("cpu", None),
+])
+def test_device_peak_flops_table(kind, expect):
+    """Peaks are keyed by device_kind: a known kind gives its peak, an
+    unknown accelerator raises, and the CPU has none (so MFU is None)."""
+    device = (jax.devices("cpu")[0] if kind == "cpu" else
+              types.SimpleNamespace(platform="tpu", device_kind=kind))
+    if expect is ValueError:
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            device_peak_flops(device)
+        with pytest.raises(ValueError, match="TPU v9 imaginary"):
+            estimate_mfu(1e12, 1.0, device=device)
+        return
+    assert device_peak_flops(device) == expect
+    mfu = estimate_mfu(197e12, 1.0, device=device)
+    assert mfu == (None if expect is None else pytest.approx(1.0))
 
 
 def test_profiler_window_state_machine(tmp_path):
@@ -255,7 +285,8 @@ def test_trainer_mfu_and_step_cost(trainer_run):
     cost = result["step_cost"]
     assert cost["flops"] > 0 and cost["peak_hbm_proxy_bytes"] > 0
     gauges = result["telemetry"]["gauges"]
-    assert 0 < gauges["hardware/mfu"]["value"]
+    # The CPU has no device peak: MFU is absent there, never a CPU number.
+    assert "hardware/mfu" not in gauges
     assert gauges["hardware/step_flops"]["value"] == cost["flops"]
     # Memoized: the extra lower+compile happens once.
     assert trainer.step_cost_analysis() is trainer.step_cost_analysis()

@@ -131,9 +131,12 @@ def test_quantized_paged_kernel_output_close_to_fp32(fmt_name, tol):
     q = jax.random.normal(jax.random.PRNGKey(2), (B, 1, Hq, D))
     qpos = jnp.full((B, 1), 100, jnp.int32)
 
+    # Quantize token-major rows (per-slot scales span heads x head_dim),
+    # then store the pools head-major, (P, Hkv, page, D), as the layer does.
+    kq, vq, scales = kvq.quantize_kv_write(k, v, fmt)
+    k, v, kq, vq = (jnp.swapaxes(x, 1, 2) for x in (k, v, kq, vq))
     o_fp32 = paged_flash_decode_forward(q, k, v, pos, tbl, qpos,
                                         interpret=True)
-    kq, vq, scales = kvq.quantize_kv_write(k, v, fmt)
     o_pal = paged_flash_decode_forward(q, kq, vq, pos, tbl, qpos,
                                        scale_pool=scales, interpret=True)
     assert float(jnp.max(jnp.abs(o_pal - o_fp32))) < tol

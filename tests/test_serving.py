@@ -104,8 +104,8 @@ def _paged_fixture():
     B, Hq, Hkv, D = 2, 4, 2, 16
     P, page = 7, 8
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    k_pool = jax.random.normal(ks[1], (P, page, Hkv, D))
-    v_pool = jax.random.normal(ks[2], (P, page, Hkv, D))
+    k_pool = jax.random.normal(ks[1], (P, Hkv, page, D))
+    v_pool = jax.random.normal(ks[2], (P, Hkv, page, D))
     # seq 0: pages [3, 1] holding 13 tokens; seq 1: page [5] holding 4.
     tbl = jnp.asarray([[3, 1, -1], [5, -1, -1]], jnp.int32)
     pos_pool = jnp.full((P, page), -1, jnp.int32)

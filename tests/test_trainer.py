@@ -450,6 +450,85 @@ def test_zero1_shards_opt_state_on_multidevice_mesh():
     assert "OK ratio=" in proc.stdout
 
 
+PER_SHARD_FLASH_SUBPROCESS = textwrap.dedent("""
+    import numpy as np
+    from repro.core.config import config_for_function
+    from repro.layers import CausalLM, Decoder, Repeat, TransformerLayer
+    from repro.trainer import optimizers as opt_lib
+    from repro.trainer.mesh_rules import KernelModifier
+    from repro.trainer.trainer import SpmdTrainer
+
+    def losses(mesh_shape, backend):
+        layer = TransformerLayer.default_config().set(input_dim=32)
+        layer.self_attention.set(num_heads=4, num_kv_heads=2)
+        layer.feed_forward.set(hidden_dim=64)
+        model = CausalLM.default_config().set(
+            decoder=Decoder.default_config().set(
+                vocab_size=32, dim=32,
+                stack=Repeat.default_config().set(
+                    layer=layer, num_layers=2, remat_policy="full")))
+        cfg = SpmdTrainer.default_config().set(
+            name="t", model=model, max_steps=3, log_every_n=1, seed=1,
+            mesh_shape=mesh_shape, mesh_axis_names=("data", "model"),
+            opt_state_sharding="zero1")
+        cfg.input.set(task="lm", vocab_size=32, seq_len=16,
+                      global_batch_size=8)
+        cfg.learner.optimizer = config_for_function(opt_lib.adamw).set(
+            peak_lr=1e-2)
+        cfg = KernelModifier.default_config().set(
+            op_overrides={"attention.fwd": backend}, interpret=True,
+            update={"block_q": 8, "block_k": 8}).instantiate().apply(cfg)
+        return [row["loss"] for row in cfg.instantiate().run()["history"]]
+
+    ref = losses((1, 1), "ref")
+    for shape in [(4, 1), (2, 2), (-1, 1)]:
+        np.testing.assert_allclose(losses(shape, "pallas"), ref, rtol=1e-4)
+
+    # A batch or head count the mesh does not divide raises; it is never
+    # silently replicated onto every device. So does a split sequence.
+    import jax.numpy as jnp
+    from repro.core.utils import make_mesh, set_mesh
+    from repro.kernels import ops
+    from repro.kernels.registry import KernelConfig
+
+    kc = KernelConfig().set(op_overrides={"attention.fwd": "pallas"},
+                            interpret=True, block_q=8, block_k=8)
+    layer_partition = (("pod", "data"), None, "model", None)
+    cases = [((4, 1), (2, 4, 2), layer_partition, "divide"),
+             ((2, 2), (4, 3, 3), layer_partition, "divide"),
+             ((2, 2), (4, 4, 2), ("data", "model", None, None), "whole")]
+    for mesh_shape, (b, hq, hkv), partition, why in cases:
+        q = jnp.ones((b, 16, hq, 8))
+        kv = jnp.ones((b, 16, hkv, 8))
+        with set_mesh(make_mesh(mesh_shape, ("data", "model"))):
+            try:
+                ops.flash_attention(q, kv, kv, kernel=kc,
+                                    partition=partition)
+            except ValueError as e:
+                assert why in str(e), e
+            else:
+                raise AssertionError(f"{mesh_shape} {q.shape} ran")
+    print("OK")
+""")
+
+
+def test_flash_attention_runs_per_shard_on_multidevice_mesh():
+    """GSPMD cannot partition a Pallas kernel: on a multi-device mesh the
+    flash-attention call runs per shard under the layer's partition (batch
+    over "data", heads over "model"), training matches the reference on one
+    device, and a split the kernel cannot take raises. Runs in a subprocess
+    with 4 CPU devices."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4").strip()
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = os.path.abspath(src) + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", PER_SHARD_FLASH_SUBPROCESS],
+                          env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "OK" in proc.stdout
+
+
 def test_zero1_partition_spec_never_duplicates_axes():
     """Regression: a param already sharded over 'data' on one dim must not
     get 'data' again on a replicated dim (duplicate mesh axes crash
