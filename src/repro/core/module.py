@@ -9,7 +9,10 @@ child, which:
   * splits the PRNG key deterministically by child name,
   * gives the child a place to emit summaries and module outputs (e.g. MoE
     load-balance losses) that are collected up the stack *without any
-    ancestor layer knowing about them*.
+    ancestor layer knowing about them*,
+  * opens a ``jax.named_scope`` named after the module (``<name>.<method>``
+    for a method other than ``forward``), so every op the invocation stages
+    carries the module path in its HLO ``op_name`` and in the device trace.
 
 The root entrypoint is :func:`functional` (the analogue of AXLearn's ``F``),
 which runs a module method under a fresh root context and returns
@@ -108,16 +111,27 @@ class InvocationContext:
     is_training: bool
     prng_key: Optional[jax.Array]
     output_collection: OutputCollection
+    method: str = "forward"
+    _scope: Any = dataclasses.field(default=None, init=False, repr=False)
 
     # --- stack management ---------------------------------------------------
 
     def __enter__(self) -> "InvocationContext":
+        # Naming only: the ops keep the scope in their ``op_name`` (and XLA
+        # may name an instruction after it); the computation is unchanged.
+        name = self.module.name
+        if self.method != "forward":
+            name = f"{name}.{self.method}"
+        self._scope = jax.named_scope(name)
+        self._scope.__enter__()
         _CONTEXT_STACK.stack.append(self)
         return self
 
     def __exit__(self, *exc):
         popped = _CONTEXT_STACK.stack.pop()
         assert popped is self
+        self._scope.__exit__(*exc)
+        self._scope = None
         return False
 
     def child(
@@ -127,8 +141,10 @@ class InvocationContext:
         state: Any = None,
         prng_key: Optional[jax.Array] = None,
         output_collection: Optional[OutputCollection] = None,
+        method: str = "forward",
     ) -> "InvocationContext":
-        """Creates the context for invoking ``module`` as a child of this one."""
+        """Creates the context for invoking ``module.<method>`` as a child of
+        this one."""
         name = module.name
         if state is None:
             state = self.state.get(name, {}) if isinstance(self.state, dict) else {}
@@ -145,6 +161,7 @@ class InvocationContext:
             output_collection=(
                 output_collection if output_collection is not None else self.output_collection
             ),
+            method=method,
         )
 
     # --- side-output API ----------------------------------------------------
@@ -210,7 +227,7 @@ class _AutoContextMeta(type):
                 # Re-entrant call on the same module (e.g. forward calling a
                 # sibling public method): stay in the current frame.
                 return fn(self, *args, **kwargs)
-            with ctx.child(self):
+            with ctx.child(self, method=fn.__name__):
                 return fn(self, *args, **kwargs)
 
         wrapped._ctx_wrapped = True
@@ -395,6 +412,7 @@ def functional(
         is_training=is_training,
         prng_key=prng_key,
         output_collection=collection,
+        method=method,
     )
     fn = getattr(type(module), method)
     fn = getattr(fn, "_original", fn)

@@ -219,7 +219,7 @@ def _fwd_impl(
         scale=scale,
     )
 
-    out, lse = pl.pallas_call(
+    fwd = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -244,7 +244,10 @@ def _fwd_impl(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qh, kh, vh)
+        name="flash_fwd",
+    )
+    with jax.named_scope("flash_fwd"):
+        out, lse = fwd(qh, kh, vh)
 
     out = out.reshape(B, Hq, S_pad, D).transpose(0, 2, 1, 3)[:, :S]
     lse = lse[..., 0].reshape(B, Hq, S_pad)[:, :, :S]
@@ -446,7 +449,7 @@ def _bwd_impl(
         logit_softcap=logit_softcap, scale=scale,
     )
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, num_kv_blocks=num_kv_blocks, **common),
         grid=(B * Hq, num_q_blocks, num_kv_blocks),
         in_specs=[
@@ -464,7 +467,10 @@ def _bwd_impl(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qh, kh, vh, doh, lse_h, delta)
+        name="flash_dq",
+    )
+    with jax.named_scope("flash_dq"):
+        dq = dq_call(qh, kh, vh, doh, lse_h, delta)
 
     # dKV pass: one sequential sweep over every (group head, q block) pair
     # per KV block, accumulating in VMEM scratch.
@@ -479,7 +485,7 @@ def _bwd_impl(
         row = (bhkv // Hkv) * Hq + (bhkv % Hkv) * G + t // num_q_blocks
         return (row, t % num_q_blocks, 0)
 
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q_blocks=num_q_blocks,
                           group_steps=G * num_q_blocks, **common),
         grid=(B * Hkv, num_kv_blocks, G * num_q_blocks),
@@ -507,7 +513,10 @@ def _bwd_impl(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qh, kh, vh, doh, lse_h, delta)
+        name="flash_dkv",
+    )
+    with jax.named_scope("flash_dkv"):
+        dk, dv = dkv_call(qh, kh, vh, doh, lse_h, delta)
 
     dq = dq.reshape(B, Hq, S_pad, D).transpose(0, 2, 1, 3)[:, :S]
     dk = dk.reshape(B, Hkv, T_pad, D).transpose(0, 2, 1, 3)[:, :T]

@@ -290,7 +290,7 @@ def flash_decode_forward(
         scale=scale,
     )
 
-    out = pl.pallas_call(
+    decode = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -311,7 +311,10 @@ def flash_decode_forward(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, k, v, qpos_rows, k_positions)
+        name="paged_decode",
+    )
+    with jax.named_scope("paged_decode"):
+        out = decode(qr, k, v, qpos_rows, k_positions)
 
     # (B, Hkv, R, D) -> (B, S', Hq, D).
     out = out[:, :, :R].reshape(B, Hkv, Sq, G, D).transpose(0, 2, 1, 3, 4)
@@ -400,7 +403,7 @@ def paged_flash_decode_forward(
         ],
     )
 
-    out = pl.pallas_call(
+    decode = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R_pad, D), q.dtype),
@@ -408,7 +411,10 @@ def paged_flash_decode_forward(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(*operands)
+        name="paged_decode",
+    )
+    with jax.named_scope("paged_decode"):
+        out = decode(*operands)
 
     out = out[:, :, :R].reshape(B, Hkv, Sq, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, Sq, Hq, D)

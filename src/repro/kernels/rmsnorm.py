@@ -43,7 +43,7 @@ def rmsnorm_forward(
     if n_pad != n:
         x2 = jnp.pad(x2, ((0, n_pad - n), (0, 0)))
 
-    out = pl.pallas_call(
+    norm = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
         grid=(n_pad // block_rows,),
         in_specs=[
@@ -56,5 +56,8 @@ def rmsnorm_forward(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(x2, scale.reshape(1, D))
+        name="rmsnorm",
+    )
+    with jax.named_scope("rmsnorm"):
+        out = norm(x2, scale.reshape(1, D))
     return out[:n].reshape(orig_shape)

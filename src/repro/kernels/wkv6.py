@@ -136,7 +136,7 @@ def wkv6_forward(
 
     kernel = functools.partial(_kernel, chunk=C, num_chunks=n_chunks,
                                num_heads=H)
-    out, s_out = pl.pallas_call(
+    wkv = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -160,7 +160,10 @@ def wkv6_forward(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(rh, kh, vh, wh, uh, sh)
+        name="wkv6",
+    )
+    with jax.named_scope("wkv6"):
+        out, s_out = wkv(rh, kh, vh, wh, uh, sh)
 
     out = out.reshape(B, H, T, V).transpose(0, 2, 1, 3)[:, :T_in]
     return out, s_out.reshape(B, H, K, V)

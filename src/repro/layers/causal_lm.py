@@ -73,8 +73,9 @@ class CausalLM(BaseLayer):
             input_embeddings=batch.get("input_embeddings"),
             positions=batch.get("positions"),
         )
-        loss, metrics = cross_entropy(
-            logits, batch["labels"], z_loss_scale=self.config.z_loss_scale)
+        with jax.named_scope("loss"):
+            loss, metrics = cross_entropy(
+                logits, batch["labels"], z_loss_scale=self.config.z_loss_scale)
         self.add_summary("loss", loss)
         self.add_summary("accuracy", metrics["accuracy"])
         return loss, {"logits": logits, **metrics}
@@ -98,18 +99,21 @@ class CausalLM(BaseLayer):
         def body(carry, xs):
             nll_sum, correct, count = carry
             h_c, l_c = xs
-            logits = decoder.head(h_c).astype(jnp.float32)
-            valid = l_c != IGNORE_TARGET
-            safe = jnp.where(valid, l_c, 0)
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            lab = jnp.take_along_axis(logits, safe[..., None], axis=-1)[..., 0]
-            nll = logz - lab
-            if cfg.z_loss_scale > 0.0:
-                nll = nll + cfg.z_loss_scale * jnp.square(logz)
-            nll_sum = nll_sum + jnp.sum(jnp.where(valid, nll, 0.0))
-            correct = correct + jnp.sum(
-                jnp.where(valid, jnp.argmax(logits, -1) == safe, 0))
-            count = count + jnp.sum(valid)
+            logits = decoder.head(h_c)
+            with jax.named_scope("loss"):
+                logits = logits.astype(jnp.float32)
+                valid = l_c != IGNORE_TARGET
+                safe = jnp.where(valid, l_c, 0)
+                logz = jax.nn.logsumexp(logits, axis=-1)
+                lab = jnp.take_along_axis(
+                    logits, safe[..., None], axis=-1)[..., 0]
+                nll = logz - lab
+                if cfg.z_loss_scale > 0.0:
+                    nll = nll + cfg.z_loss_scale * jnp.square(logz)
+                nll_sum = nll_sum + jnp.sum(jnp.where(valid, nll, 0.0))
+                correct = correct + jnp.sum(
+                    jnp.where(valid, jnp.argmax(logits, -1) == safe, 0))
+                count = count + jnp.sum(valid)
             return (nll_sum, correct, count), None
 
         body = jax.checkpoint(body, prevent_cse=False)
@@ -181,7 +185,8 @@ class MaskedLM(BaseLayer):
         x = jnp.where(mask[..., None], self.state["mask_emb"].astype(x.dtype), x)
         logits = self.decoder(None, input_embeddings=x)
         labels = jnp.where(mask, batch["labels"], IGNORE_TARGET)
-        loss, metrics = cross_entropy(logits, labels)
+        with jax.named_scope("loss"):
+            loss, metrics = cross_entropy(logits, labels)
         self.add_summary("loss", loss)
         return loss, {"logits": logits, **metrics}
 

@@ -9,13 +9,13 @@ The one subsystem every layer reports through (paper §operations):
 * :mod:`repro.observability.tracing` — span :class:`Tracer` emitting
   Chrome trace-event JSON (open in Perfetto), per-rank pid lanes, fleet
   merge + schema validation.
-* :mod:`repro.observability.hardware` — compiled-step FLOPs → MFU,
+* :mod:`repro.observability.hardware` — compiled-step FLOPs → HFU,
   ``device.memory_stats()`` gauges, on-demand ``jax.profiler`` windows.
 * :mod:`repro.observability.runtime` — ``ObservabilityConfig`` + the
   per-process bundle subsystems instantiate.
 
 Instrumented call sites: ``SpmdTrainer`` (step/data-wait/ckpt-stall spans,
-summary routing, MFU gauges), ``serving.scheduler``/``gateway`` (request
+summary routing, HFU gauges), ``serving.scheduler``/``gateway`` (request
 lifecycle spans, latency reservoirs, queue/pool gauges), and
 ``launch.distributed`` workers + ``FleetSupervisor`` (per-rank traces
 merged into one fleet timeline, step-boundary straggler skew).

@@ -1,20 +1,18 @@
-"""Hardware-utilization hooks: MFU from XLA cost analysis, HBM gauges,
-and an on-demand ``jax.profiler`` window.
+"""Hardware-utilization hooks: FLOPs utilization from XLA cost analysis,
+HBM gauges, and an on-demand ``jax.profiler`` window.
 
-MFU (model FLOPs utilization) is THE cross-hardware efficiency number
-(Modalities/PaLM convention): achieved model FLOP/s over the chip's peak.
-The numerator comes from the *compiled* train step's own
+The trainer's numerator comes from the *compiled* train step's own
 ``cost_analysis()`` — what XLA will actually execute, including remat
-recompute — so it needs no analytical per-arch FLOP formula. The
-denominator is a peak table keyed by ``device_kind`` (overridable per run,
-``peak_flops_per_device``, because "the" peak depends on dtype). A device
-that is not in the table is an error, and the CPU has no peak: MFU there is
-``None``, never a number.
+recompute — so it needs no analytical per-arch FLOP formula, and what it
+gauges is hardware FLOPs utilization (``hardware/hfu``), not model FLOPs
+utilization. The denominator is a peak table keyed by ``device_kind``
+(overridable per run, ``peak_flops_per_device``, because "the" peak depends
+on dtype). A device that is not in the table is an error, and the CPU has
+no peak: utilization there is ``None``, never a number.
 """
 
 from __future__ import annotations
 
-import contextlib
 from typing import Dict, Optional
 
 import jax
@@ -65,7 +63,8 @@ def compiled_cost(compiled) -> Dict[str, Optional[float]]:
 def estimate_mfu(flops_per_step: Optional[float], step_time_s: float, *,
                  num_devices: int = 1, device=None,
                  peak_flops_per_device: float = 0.0) -> Optional[float]:
-    """Achieved model FLOP/s over aggregate peak. None when unmeasurable:
+    """Achieved FLOP/s over aggregate peak: MFU for model FLOPs, HFU for
+    compiled FLOPs (what the trainer passes). None when unmeasurable:
     no FLOPs, no step time, or a CPU ``device`` (default: the first
     device).
 
@@ -149,14 +148,3 @@ class ProfilerWindow:
         """Stop a still-open window (run ended early / preemption)."""
         if self.active:
             self._stop()
-
-
-@contextlib.contextmanager
-def profiler_window(logdir: str):
-    """Imperative capture of an arbitrary block (notebooks, benches)."""
-    w = ProfilerWindow(logdir, start_step=0, stop_step=0)
-    w.on_step_start(0)
-    try:
-        yield w
-    finally:
-        w.close()

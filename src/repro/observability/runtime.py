@@ -33,9 +33,9 @@ class ObservabilityConfig(ConfigBase):
                          N..M; -1 = off).
     ``rank``           — process index: the pid lane in merged fleet traces.
     ``mfu``            — compute compiled-step FLOPs once and gauge per-step
-                         MFU (costs one extra lower+compile, off the step
-                         path).
-    ``peak_flops_per_device`` — MFU denominator override (0 = the peak
+                         utilization ``hardware/hfu`` (costs one extra
+                         lower+compile, off the step path).
+    ``peak_flops_per_device`` — utilization denominator override (0 = the peak
                          table keyed by ``device_kind``).
     ``reservoir_size`` — histogram reservoir bound.
     """

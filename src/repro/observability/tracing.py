@@ -10,6 +10,9 @@ request: queued → prefill → decode lanes on the serving side.
 All tracing is host-side and outside jit — a span is two ``time`` calls
 and a dict append, so instrumenting the hot loop adds zero retraces and
 microsecond-scale per-span cost (measured by ``bench_observability``).
+A ``span`` is also a ``jax.profiler.TraceAnnotation`` of the same name, so
+while a profiler capture is open (``ProfilerWindow``) the phase appears on
+the device trace's clock beside the device ops it dispatched.
 
 Wall-clock (``time.time``) is the default timebase so traces from
 different processes on one host merge onto a common axis. (Cross-host
@@ -28,6 +31,8 @@ import contextlib
 import json
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Union
+
+import jax
 
 __all__ = ["Tracer", "merge_traces", "validate_chrome_trace", "load_trace"]
 
@@ -67,12 +72,14 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, name: str, *, tid: int = 0, **args):
-        """Wraps a block in a complete ("X") event. Nesting follows the
-        call stack naturally: an inner span's [ts, ts+dur] lies inside the
-        outer's, which is exactly what the Chrome viewer stacks."""
+        """Wraps a block in a complete ("X") event and a profiler
+        annotation. Nesting follows the call stack naturally: an inner
+        span's [ts, ts+dur] lies inside the outer's, which is exactly what
+        the Chrome viewer stacks."""
         t0 = self._time()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
             t1 = self._time()
             self.add_span(name, t0, t1, tid=tid, **args)

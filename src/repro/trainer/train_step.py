@@ -364,28 +364,29 @@ def build_train_step(
         step_key = jax.random.fold_in(state["prng_key"], state["step"])
         total, parts, grads = compute_grads(state["params"], batch, step_key)
         state_updates = parts.pop("state_updates", None)
-        new_params, new_opt = learner.apply_updates(
-            grads, state["opt_state"], state["params"],
-            update_partition_specs=update_partition_specs,
-            param_partition_specs=param_partition_specs)
-        if state_updates:
-            # Forward-pass state (fp8 amax histories) overwrites the
-            # optimizer's view of those leaves — they are carried as
-            # params only so they checkpoint/shard like everything else.
-            new_params = apply_state_updates(new_params, state_updates)
-        # Norm telemetry: grad/param/update norms are the first things a
-        # diverging run's operator looks at, so they come out of every step
-        # (computed inside jit — no extra dispatches, no retraces).
-        update = jax.tree.map(
-            lambda n, o: n.astype(jnp.float32) - o.astype(jnp.float32),
-            new_params, state["params"])
-        metrics = {
-            "total_loss": total,
-            "grad_norm": global_norm(grads),
-            "param_norm": global_norm(new_params),
-            "update_norm": global_norm(update),
-            **parts,
-        }
+        # The learner has no module scope (``apply_updates`` is structural),
+        # so the update gets one of its own. The norm telemetry shares it:
+        # XLA fuses those reductions into the update's fusions.
+        with jax.named_scope("learner"):
+            new_params, new_opt = learner.apply_updates(
+                grads, state["opt_state"], state["params"],
+                update_partition_specs=update_partition_specs,
+                param_partition_specs=param_partition_specs)
+            if state_updates:
+                # Forward-pass state (fp8 amax histories) overwrites the
+                # optimizer's view of those leaves — they are carried as
+                # params only so they checkpoint/shard like everything else.
+                new_params = apply_state_updates(new_params, state_updates)
+            # Norm telemetry: grad/param/update norms are the first things a
+            # diverging run's operator looks at, so they come out of every
+            # step (computed inside jit — no extra dispatches, no retraces).
+            update = jax.tree.map(
+                lambda n, o: n.astype(jnp.float32) - o.astype(jnp.float32),
+                new_params, state["params"])
+            norms = {"grad_norm": global_norm(grads),
+                     "param_norm": global_norm(new_params),
+                     "update_norm": global_norm(update)}
+        metrics = {"total_loss": total, **norms, **parts}
         new_state = {
             "step": state["step"] + 1,
             "prng_key": state["prng_key"],

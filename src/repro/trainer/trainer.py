@@ -146,7 +146,7 @@ class SpmdTrainer(Module):
         # CPU dry-run substitution).
         offload_optimizer_state: bool = False
         # Unified observability (repro.observability): metrics registry +
-        # JSONL sink, Chrome trace spans per step phase, MFU/memory gauges,
+        # JSONL sink, Chrome trace spans per step phase, HFU/memory gauges,
         # on-demand profiler window. None = zero instrumentation.
         observability: Optional[ObservabilityConfig] = None
         # Runtime resiliency (paper §5).
@@ -431,7 +431,7 @@ class SpmdTrainer(Module):
     @no_context
     def step_cost_analysis(self) -> Dict[str, Any]:
         """XLA's own analysis of the compiled train step: ``flops`` (the
-        MFU numerator), ``bytes_accessed``, and ``peak_hbm_proxy_bytes``
+        HFU numerator), ``bytes_accessed``, and ``peak_hbm_proxy_bytes``
         (argument + temp + output bytes of the executable).
 
         Memoized per trainer; the one extra lower+compile happens off the
@@ -576,6 +576,7 @@ class SpmdTrainer(Module):
                                  on_timeout=cfg.watchdog_on_timeout)
             history = []
             t0 = time.time()
+            fetched = None  # (perf_counter, step) of the last logging fetch
             last_metrics = {}
             try:
                 for step in range(start_step, num_steps):
@@ -605,32 +606,37 @@ class SpmdTrainer(Module):
                     # The first invocation traces + XLA-compiles; attribute
                     # it to "compile" (it includes that one step's compute).
                     warm = self._step_has_run
-                    t_step = time.perf_counter()
                     with phase("compile" if not warm else "step", step=step):
                         state, metrics = step_fn(state, batch)
                         if (not warm and obs is not None and obs.config.mfu
                                 and not cfg.distributed):
-                            # Pre-pay the MFU numerator's one extra AOT
+                            # Pre-pay the HFU numerator's one extra AOT
                             # lower+compile here, in the compile bucket —
                             # never in a warm step (which must stay within
                             # the <1% instrumentation budget).
                             self.step_cost_analysis()
-                    step_dur = time.perf_counter() - t_step
                     self._step_has_run = True
                     if obs is not None:
                         obs.profiler.on_step_end(step)
                     if cfg.sdc_check_every_n and step % cfg.sdc_check_every_n == 0:
                         self._sdc_check(batch)
                     if step % cfg.log_every_n == 0 or step == num_steps - 1:
-                        m = _flatten_metrics(metrics)
+                        m = _flatten_metrics(metrics)  # blocks on the step
+                        # Dispatch is asynchronous, so the step time is the
+                        # wall clock between two blocking fetches over the
+                        # steps between them; the compile comes before the
+                        # first fetch and is in no interval.
+                        now = time.perf_counter()
+                        step_dur = ((now - fetched[0]) / (step - fetched[1])
+                                    if fetched is not None else None)
+                        fetched = (now, step)
                         m["step"] = step
                         m["steps_per_s"] = (step - start_step + 1) / (time.time() - t0)
                         history.append(m)
                         last_metrics = m
                         if registry is not None:
                             self._export_step_metrics(
-                                registry, m, step,
-                                step_dur=step_dur if warm else None,
+                                registry, m, step, step_dur=step_dur,
                                 tokens_per_step=tokens_per_step)
                     if (cfg.checkpointer is not None and cfg.checkpoint_every_n
                             and (step + 1) % cfg.checkpoint_every_n == 0):
@@ -686,9 +692,12 @@ class SpmdTrainer(Module):
         """Routes one logging step's metrics into the registry: gauges keyed
         ``train/<metric>`` and ``summaries/<module-path>`` (the values
         modules ``add_summary``'d, routed out of the jitted step), plus
-        hardware gauges — per-step MFU from the compiled step's own cost
-        analysis, tokens/s/device, and ``device.memory_stats()`` where the
-        backend reports them (TPU/GPU peak HBM; empty on CPU)."""
+        hardware gauges — ``hardware/hfu`` (hardware FLOPs utilization: the
+        compiled step's own FLOPs, remat recompute included, over peak),
+        tokens/s/device over ``step_dur`` (seconds per step between two
+        blocking fetches; None when there is no earlier fetch), and
+        ``device.memory_stats()`` where the backend reports them (TPU/GPU
+        peak HBM; empty on CPU)."""
         obs = self.observability
         for k, v in m.items():
             if k == "step":
@@ -705,11 +714,11 @@ class SpmdTrainer(Module):
                     tokens_per_step / step_dur / n_dev)
             if obs.config.mfu:
                 cost = self.step_cost_analysis()
-                mfu = estimate_mfu(
+                hfu = estimate_mfu(
                     cost.get("flops"), step_dur, num_devices=n_dev,
                     peak_flops_per_device=obs.config.peak_flops_per_device)
-                if mfu is not None:
-                    registry.gauge("hardware/mfu").set(mfu)
+                if hfu is not None:
+                    registry.gauge("hardware/hfu").set(hfu)
                 if cost.get("flops"):
                     registry.gauge("hardware/step_flops").set(cost["flops"])
                 if cost.get("peak_hbm_proxy_bytes"):

@@ -1,5 +1,7 @@
 """Tests for Module + InvocationContext (paper §4.3, Figure 3)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -179,3 +181,39 @@ def test_duplicate_child_rejected():
 
     with pytest.raises(ValueError, match="Duplicate child"):
         M.default_config().set(name="m").instantiate()
+
+
+def test_invocations_name_their_ops():
+    """Each invocation runs under a named scope: the module's name for
+    ``forward``, ``<name>.<method>`` for another method, none for a
+    re-entrant call on the same module. The compiled HLO keeps it."""
+
+    class Scoped(Module):
+        @config_class
+        class Config(Module.Config):
+            a: Leaf.Config = Leaf.Config()
+            b: Leaf.Config = Leaf.Config()
+
+        def __init__(self, cfg, *, parent=None):
+            super().__init__(cfg, parent=parent)
+            self._add_child("a", cfg.a)
+            self._add_child("b", cfg.b)
+
+        def helper(self, x):
+            return jnp.sin(x)
+
+        def forward(self, x):
+            return self.helper(self.a(x)) + self.b.stateful(x)
+
+    root = Scoped.default_config().set(name="root").instantiate()
+
+    def f(state, x):
+        return functional(root, state=state, inputs=(x,))[0]
+
+    state = {"b": {"w": jnp.ones(3)}}
+    text = jax.jit(f).lower(state, jnp.arange(3.0)).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert "jit(f)/root/a/mul" in names
+    assert "jit(f)/root/b.stateful/add" in names
+    assert "jit(f)/root/sin" in names
+    assert not any("helper" in n for n in names)

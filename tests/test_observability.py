@@ -1,8 +1,11 @@
 """Unified observability: registry semantics, trace schema, trainer/serving
 instrumentation, zero-retrace + overhead budgets, fleet trace merge."""
 
+import glob
 import json
 import os
+import re
+import time
 import types
 
 import jax
@@ -212,6 +215,27 @@ def test_profiler_window_state_machine(tmp_path):
     assert not w.active
 
 
+def test_tracer_span_appears_in_profiler_capture(tmp_path):
+    """A span is also a profiler annotation: inside a capture it lands on
+    the profiler's host timeline under its own name."""
+    from jax.profiler import ProfileData
+
+    tracer = Tracer()
+    w = ProfilerWindow(str(tmp_path), start_step=0, stop_step=0)
+    w.on_step_start(0)
+    with tracer.span("decode_step", batch=3):
+        jnp.sin(jnp.arange(8.0)).block_until_ready()
+    w.on_step_end(0)
+    assert w.captured and not w.error
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert "decode_step" in names
+    # The Chrome trace keeps its span as before.
+    assert [e["name"] for e in tracer.events] == ["decode_step"]
+
+
 # --------------------------- trainer integration -----------------------------
 
 
@@ -285,8 +309,8 @@ def test_trainer_mfu_and_step_cost(trainer_run):
     cost = result["step_cost"]
     assert cost["flops"] > 0 and cost["peak_hbm_proxy_bytes"] > 0
     gauges = result["telemetry"]["gauges"]
-    # The CPU has no device peak: MFU is absent there, never a CPU number.
-    assert "hardware/mfu" not in gauges
+    # The CPU has no device peak: HFU is absent there, never a CPU number.
+    assert "hardware/hfu" not in gauges
     assert gauges["hardware/step_flops"]["value"] == cost["flops"]
     # Memoized: the extra lower+compile happens once.
     assert trainer.step_cost_analysis() is trainer.step_cost_analysis()
@@ -313,6 +337,49 @@ def test_trainer_zero_retrace_with_observability_on(trainer_run):
     # lower+compile is a separate executable, not a _jit_step retrace.)
     assert trainer._jit_step._cache_size() == 1, \
         "observability instrumentation caused a retrace"
+
+
+def test_trainer_step_time_spans_blocking_fetches(monkeypatch):
+    """The step time is the wall clock between two blocking logging fetches
+    over the steps between them (dispatch is asynchronous, so timing the
+    call would time the enqueue); the first fetch opens the first interval."""
+    from repro.trainer import trainer as trainer_mod
+
+    clock = iter(range(0, 1000, 10))  # the trainer reads it once per fetch
+    monkeypatch.setattr(trainer_mod, "time", types.SimpleNamespace(
+        time=time.time, perf_counter=lambda: float(next(clock))))
+    obs = ObservabilityConfig(mfu=False)
+    result = _tiny_trainer_cfg(steps=6, observability=obs).instantiate().run()
+    snap = result["telemetry"]
+    # Fetches at steps 0, 2, 4, 5 read 0, 10, 20, 30: 10/2, 10/2, 10/1.
+    hist = snap["histograms"]["train/step_time_s"]
+    assert (hist["count"], hist["sum"], hist["max"]) == (3, 20.0, 10.0)
+    assert snap["gauges"]["train/tokens_per_s"]["value"] == 8 * 16 / 10.0
+
+
+def test_train_step_ops_carry_module_scopes():
+    """The compiled train step names its ops after the modules and the
+    explicit scopes (``loss``, ``learner``, the Pallas kernels), through
+    remat's recomputation and the backward pass."""
+    from repro.kernels.registry import KernelConfig
+
+    cfg = _tiny_trainer_cfg(steps=1)
+    stack = cfg.model.decoder.stack
+    stack.set(remat_policy="full")
+    stack.layer.self_attention.set(kernel=KernelConfig().set(interpret=True))
+    trainer = cfg.instantiate()
+    state = trainer.init_state()
+    batch = trainer.input.make_batch(0)
+    text = jax.jit(trainer.make_train_step()).lower(
+        state, batch).compile().as_text()
+    paths = [p.split("/") for p in re.findall(r'op_name="([^"]*)"', text)]
+    scopes = {e for p in paths for e in p}
+    for name in ("self_attention", "feed_forward", "emb.attend", "loss",
+                 "learner", "flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in scopes, name
+    # The recomputed forward keeps the module path under remat's marker.
+    assert any("rematted_computation" in p and "feed_forward" in p
+               for p in paths)
 
 
 def test_trainer_without_observability_unchanged():
