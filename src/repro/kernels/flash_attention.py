@@ -7,9 +7,22 @@ running max / denominator / accumulator in VMEM scratch — the TPU analogue of
 a CUDA thread-block's registers/SMEM. Block shapes default to (128, 128) to
 align with the 128x128 MXU tile and 8x128 VREG lanes.
 
-Supports: causal masking, sliding windows, logit soft-capping, and GQA
-(q-head -> kv-head mapping happens in the BlockSpec index_map so each KV
-block is fetched once per group).
+Supports: causal masking, sliding windows, logit soft-capping, and GQA. The
+forward and dQ grids run over query heads (B*Hq) and map each to its KV head
+in the BlockSpec index_map, so a KV block is fetched once per query head, not
+once per group; the dKV grid runs over KV heads and streams its group's
+queries.
+
+Every (q block, kv block) pair of a head falls in one of three classes, read
+from the grid position, the causal flag, the window and the unpadded lengths
+(:class:`_Blocks`, counted by :func:`block_census`):
+
+  * **empty**: no score is kept. The body does not run, and the index maps
+    point the step at the block the next computing step needs, so the
+    pipeline copies nothing for it.
+  * **partial**: some scores are kept. The body builds the element mask.
+  * **full**: every score is kept, with no padded row or column. The body
+    builds no mask: its selects and guards would all be identities.
 
 Training: :func:`flash_attention` is a ``jax.custom_vjp`` whose backward is
 the standard recompute scheme (FlashAttention-2): the forward additionally
@@ -33,43 +46,191 @@ Both passes are GQA- and sliding-window-aware and validated against
 
 from __future__ import annotations
 
+import dataclasses
 import functools
-import math
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention", "flash_attention_forward"]
+__all__ = ["flash_attention", "flash_attention_forward", "block_census"]
 
 NEG_INF = -1e30
 _LANES = 128  # VREG lane count: scratch second-minor dim
 
 
-def _block_relevant(qi, kj, *, block_q: int, block_k: int, causal: bool,
-                    sliding_window: Optional[int]):
-    """Whether the (qi, kj) block pair contains any unmasked entry."""
-    relevant = True
-    if causal:
-        relevant = jnp.logical_and(relevant,
-                                   kj * block_k <= qi * block_q + block_q - 1)
-    if sliding_window is not None:
-        relevant = jnp.logical_and(
-            relevant, (kj + 1) * block_k - 1 > qi * block_q - sliding_window)
-    return relevant
+def _and(a, b):
+    """Logical and that stays a Python bool while both sides are static."""
+    if a is True or b is False:
+        return b
+    if b is True or a is False:
+        return a
+    return jnp.logical_and(a, b)
 
 
-def _pair_mask(q_pos, k_pos, *, q_len: int, kv_len: int, causal: bool,
-               sliding_window: Optional[int]):
-    """(bq, bk) boolean mask; also masks q/k padding rows/cols."""
-    mask = jnp.logical_and(k_pos < kv_len, q_pos < q_len)
-    if causal:
-        mask = jnp.logical_and(mask, k_pos <= q_pos)
-    if sliding_window is not None:
-        mask = jnp.logical_and(mask, k_pos > q_pos - sliding_window)
-    return mask
+def _not(a):
+    return (not a) if isinstance(a, bool) else jnp.logical_not(a)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Blocks:
+    """The block tiling of a (q_len, kv_len) score matrix, and which scores
+    are kept: (r, c) is kept iff r < q_len, c < kv_len, c <= r when causal
+    and c > r - sliding_window when a window is set.
+
+    :meth:`classify` is the one predicate the kernels' bodies, their index
+    maps and :func:`block_census` read.
+    """
+
+    block_q: int
+    block_k: int
+    q_len: int
+    kv_len: int
+    causal: bool
+    sliding_window: Optional[int]
+
+    @classmethod
+    def of(cls, S, T, block_q, block_k, causal, sliding_window):
+        return cls(min(block_q, S), min(block_k, T), S, T, causal,
+                   sliding_window)
+
+    @property
+    def num_q_blocks(self) -> int:
+        return -(-self.q_len // self.block_q)
+
+    @property
+    def num_kv_blocks(self) -> int:
+        return -(-self.kv_len // self.block_k)
+
+    def classify(self, qi, kj):
+        """(some score kept, every score kept) for the pair (qi, kj).
+
+        Each is a Python bool where it holds for every pair, else a traced
+        (or, from :func:`block_census`, an array) boolean.
+        """
+        q_first, k_first = qi * self.block_q, kj * self.block_k
+        q_last = q_first + self.block_q - 1
+        k_last = k_first + self.block_k - 1
+        some, every = True, True
+        # Padding rows and columns (only in the last block) are never kept;
+        # the bounds below are those of the unpadded rows and columns.
+        if self.q_len % self.block_q:
+            every = _and(every, q_last < self.q_len)
+            q_last = jnp.minimum(q_last, self.q_len - 1)
+        if self.kv_len % self.block_k:
+            every = _and(every, k_last < self.kv_len)
+            k_last = jnp.minimum(k_last, self.kv_len - 1)
+        # r - c spans [q_first - k_last, q_last - k_first] in the block.
+        if self.causal:  # kept iff r - c >= 0
+            some = _and(some, k_first <= q_last)
+            every = _and(every, k_last <= q_first)
+        if self.sliding_window is not None:  # kept iff r - c < window
+            some = _and(some, k_last > q_first - self.sliding_window)
+            every = _and(every, k_first > q_last - self.sliding_window)
+        return some, every
+
+    def mask(self, qi, kj):
+        """(block_q, block_k) mask of the kept scores of a partial pair."""
+        shape = (self.block_q, self.block_k)
+        q_pos = qi * self.block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        k_pos = kj * self.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = True
+        if self.q_len % self.block_q:
+            mask = _and(mask, q_pos < self.q_len)
+        if self.kv_len % self.block_k:
+            mask = _and(mask, k_pos < self.kv_len)
+        if self.causal:
+            mask = _and(mask, k_pos <= q_pos)
+        if self.sliding_window is not None:
+            mask = _and(mask, k_pos > q_pos - self.sliding_window)
+        return mask
+
+    def first_kv_block(self, qi):
+        """The first kv block that q block ``qi`` needs (the window's edge)."""
+        if self.sliding_window is None:
+            return 0
+        first_col = jnp.maximum(qi * self.block_q - self.sliding_window + 1, 0)
+        return jnp.minimum(first_col // self.block_k, self.num_kv_blocks - 1)
+
+    def first_q_block(self, kj):
+        """The first q block that kv block ``kj`` needs (the causal edge)."""
+        if not self.causal:
+            return 0
+        return jnp.minimum(kj * self.block_k // self.block_q,
+                           self.num_q_blocks - 1)
+
+
+def _run_blocks(blocks: _Blocks, qi, kj, body):
+    """Runs ``body(masked=True)`` on a partial pair, ``body(masked=False)``
+    on a full one, and nothing on an empty one."""
+    some, every = blocks.classify(qi, kj)
+    pl.when(_and(some, _not(every)))(functools.partial(body, True))
+    pl.when(every)(functools.partial(body, False))
+
+
+def block_census(S: int, T: int, block_q: int, block_k: int,
+                 causal: bool = True,
+                 sliding_window: Optional[int] = None) -> Dict[str, int]:
+    """How many (q block, kv block) pairs of one head's grid are empty,
+    partial and full, by the predicate the kernels run."""
+    blocks = _Blocks.of(S, T, block_q, block_k, causal, sliding_window)
+    shape = (blocks.num_q_blocks, blocks.num_kv_blocks)
+    some, every = blocks.classify(np.arange(shape[0])[:, None],
+                                  np.arange(shape[1])[None, :])
+    some = int(np.broadcast_to(np.asarray(some), shape).sum())
+    every = int(np.broadcast_to(np.asarray(every), shape).sum())
+    return {"empty": shape[0] * shape[1] - some, "partial": some - every,
+            "full": every}
+
+
+def _kv_index_map(blocks: _Blocks, Hq: int, Hkv: int):
+    """K/V index map of the (B*Hq, q block, kv block) grids (forward, dQ).
+
+    An empty step points at the block the next computing step reads: below
+    the window, its row's first needed block; past the causal edge, the next
+    row's (the last row's own, at the end of a head). Equal consecutive
+    indices are not copied again.
+    """
+    G = Hq // Hkv
+
+    def index(bh, qi, kj):
+        some, _ = blocks.classify(qi, kj)
+        if some is not True:
+            first = blocks.first_kv_block(qi)
+            after = blocks.first_kv_block(
+                jnp.minimum(qi + 1, blocks.num_q_blocks - 1))
+            kj = jnp.where(some, kj, jnp.where(kj < first, first, after))
+        b, h = bh // Hq, bh % Hq
+        return (b * Hkv + h // G, kj, 0)
+
+    return index
+
+
+def _q_group_index_map(blocks: _Blocks, Hq: int, Hkv: int):
+    """q / dO / LSE / delta index map of the dKV grid
+    (B*Hkv, kv block, group head * q block).
+
+    An empty step points at the first q block this kv block needs: of this
+    group head before the causal edge, of the next one past the window (of
+    this one, for the last group head).
+    """
+    G = Hq // Hkv
+    nq = blocks.num_q_blocks
+
+    def index(bhkv, kj, t):
+        g, qi = t // nq, t % nq
+        some, _ = blocks.classify(qi, kj)
+        if some is not True:
+            first = blocks.first_q_block(kj)
+            g = jnp.where(jnp.logical_or(some, qi < first), g,
+                          jnp.minimum(g + 1, G - 1))
+            qi = jnp.where(some, qi, first)
+        return ((bhkv // Hkv) * Hq + (bhkv % Hkv) * G + g, qi, 0)
+
+    return index
 
 
 # ---------------------------------------------------------------------------
@@ -87,13 +248,7 @@ def _fwd_kernel(
     l_scr,  # (block_q, _LANES) f32
     acc_scr,  # (block_q, D) f32
     *,
-    block_q: int,
-    block_k: int,
-    q_len: int,
-    kv_len: int,
-    num_kv_blocks: int,
-    causal: bool,
-    sliding_window: Optional[int],
+    blocks: _Blocks,
     logit_softcap: Optional[float],
     scale: float,
 ):
@@ -106,15 +261,7 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-
-    # Skip fully-masked blocks (beyond the causal frontier / outside window).
-    relevant = _block_relevant(qi, kj, block_q=block_q, block_k=block_k,
-                               causal=causal, sliding_window=sliding_window)
-
-    @pl.when(relevant)
-    def _compute():
+    def _compute(masked: bool):
         q = q_ref[0].astype(jnp.float32) * scale  # (bq, D)
         k = k_ref[0].astype(jnp.float32)  # (bk, D)
         s = jax.lax.dot_general(
@@ -122,18 +269,23 @@ def _fwd_kernel(
         if logit_softcap is not None:
             s = logit_softcap * jnp.tanh(s / logit_softcap)
 
-        mask = _pair_mask(q_pos, k_pos, q_len=q_len, kv_len=kv_len,
-                          causal=causal, sliding_window=sliding_window)
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            mask = blocks.mask(qi, kj)
+            s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[:, 0:1]  # (bq, 1)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        # Guard fully-masked rows: keep exp argument finite.
-        p = jnp.exp(s - jnp.where(m_new <= NEG_INF / 2, 0.0, m_new))
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - jnp.where(m_new <= NEG_INF / 2, 0.0, m_new))
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
+        # Guard fully-masked rows: keep exp argument finite. Unmasked, every
+        # row keeps a score, so m_new is finite and exp(NEG_INF - m_new) is
+        # already 0: the guards would be identities.
+        m_safe = (jnp.where(m_new <= NEG_INF / 2, 0.0, m_new) if masked
+                  else m_new)
+        p = jnp.exp(s - m_safe)
+        alpha = jnp.exp(m_prev - m_safe)
+        if masked:
+            p = jnp.where(mask, p, 0.0)
+            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
 
         l_new = alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
         v = v_ref[0].astype(jnp.float32)  # (bk, D)
@@ -143,7 +295,9 @@ def _fwd_kernel(
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(kj == num_kv_blocks - 1)
+    _run_blocks(blocks, qi, kj, _compute)
+
+    @pl.when(kj == blocks.num_kv_blocks - 1)
     def _finalize():
         l = l_scr[:, 0:1]
         denom = jnp.where(l == 0.0, 1.0, l)
@@ -174,13 +328,12 @@ def _fwd_impl(
     B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
     assert Hq % Hkv == 0
-    G = Hq // Hkv
-    block_q = min(block_q, S)
-    block_k = min(block_k, T)
+    blocks = _Blocks.of(S, T, block_q, block_k, causal, sliding_window)
+    block_q, block_k = blocks.block_q, blocks.block_k
 
     # Pad sequence dims to block multiples (mask handles the tail).
-    S_pad = -(-S // block_q) * block_q
-    T_pad = -(-T // block_k) * block_k
+    S_pad = blocks.num_q_blocks * block_q
+    T_pad = blocks.num_kv_blocks * block_k
     if S_pad != S:
         q = jnp.pad(q, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
     if T_pad != T:
@@ -192,29 +345,16 @@ def _fwd_impl(
     kh = k.transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, D)
     vh = v.transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, D)
 
-    num_q_blocks = S_pad // block_q
-    num_kv_blocks = T_pad // block_k
-    grid = (B * Hq, num_q_blocks, num_kv_blocks)
+    grid = (B * Hq, blocks.num_q_blocks, blocks.num_kv_blocks)
 
     def q_index(bh, qi, kj):
         return (bh, qi, 0)
 
-    def kv_index(bh, qi, kj):
-        b, h = bh // Hq, bh % Hq
-        return (b * Hkv + h // G, kj, 0)
-
-    def lse_index(bh, qi, kj):
-        return (bh, qi, 0)
+    kv_index = _kv_index_map(blocks, Hq, Hkv)
 
     kernel = functools.partial(
         _fwd_kernel,
-        block_q=block_q,
-        block_k=block_k,
-        q_len=S,
-        kv_len=T,
-        num_kv_blocks=num_kv_blocks,
-        causal=causal,
-        sliding_window=sliding_window,
+        blocks=blocks,
         logit_softcap=logit_softcap,
         scale=scale,
     )
@@ -229,7 +369,7 @@ def _fwd_impl(
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_q, _LANES), lse_index),
+            pl.BlockSpec((1, block_q, _LANES), q_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, S_pad, D), q.dtype),
@@ -263,15 +403,20 @@ def _recompute_p_ds(q, k, lse, do, v, delta, mask, *, logit_softcap, scale):
     """Shared recompute: returns (p, ds_raw), both (bq, bk) fp32.
 
     ``lse``/``delta`` are (bq, 1). Invalid rows carry lse = NEG_INF.
+    ``mask`` is None on a full pair: every row keeps a score there, so its
+    lse is finite.
     """
     s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if logit_softcap is not None:
         s = logit_softcap * jnp.tanh(s / logit_softcap)
-    row_valid = lse > NEG_INF / 2  # (bq, 1)
-    lse_safe = jnp.where(row_valid, lse, 0.0)
-    p = jnp.exp(s - lse_safe)
-    p = jnp.where(jnp.logical_and(mask, row_valid), p, 0.0)
+    if mask is None:
+        p = jnp.exp(s - lse)
+    else:
+        row_valid = lse > NEG_INF / 2  # (bq, 1)
+        lse_safe = jnp.where(row_valid, lse, 0.0)
+        p = jnp.exp(s - lse_safe)
+        p = jnp.where(jnp.logical_and(mask, row_valid), p, 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     ds = p * (dp - delta)
@@ -285,13 +430,7 @@ def _dq_kernel(
     dq_ref,
     dq_scr,  # (block_q, D) f32
     *,
-    block_q: int,
-    block_k: int,
-    q_len: int,
-    kv_len: int,
-    num_kv_blocks: int,
-    causal: bool,
-    sliding_window: Optional[int],
+    blocks: _Blocks,
     logit_softcap: Optional[float],
     scale: float,
 ):
@@ -302,17 +441,8 @@ def _dq_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    relevant = _block_relevant(qi, kj, block_q=block_q, block_k=block_k,
-                               causal=causal, sliding_window=sliding_window)
-
-    @pl.when(relevant)
-    def _compute():
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = _pair_mask(q_pos, k_pos, q_len=q_len, kv_len=kv_len,
-                          causal=causal, sliding_window=sliding_window)
+    def _compute(masked: bool):
+        mask = blocks.mask(qi, kj) if masked else None
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
@@ -325,7 +455,9 @@ def _dq_kernel(
         dq_scr[...] = dq_scr[...] + scale * jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    @pl.when(kj == num_kv_blocks - 1)
+    _run_blocks(blocks, qi, kj, _compute)
+
+    @pl.when(kj == blocks.num_kv_blocks - 1)
     def _finalize():
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -335,37 +467,22 @@ def _dkv_kernel(
     dk_ref, dv_ref,
     dk_scr, dv_scr,  # (block_k, D) f32
     *,
-    block_q: int,
-    block_k: int,
-    q_len: int,
-    kv_len: int,
-    num_q_blocks: int,
+    blocks: _Blocks,
     group_steps: int,  # G * num_q_blocks
-    causal: bool,
-    sliding_window: Optional[int],
     logit_softcap: Optional[float],
     scale: float,
 ):
     kj = pl.program_id(1)
     t = pl.program_id(2)  # g * num_q_blocks + qi
-    qi = t % num_q_blocks
+    qi = t % blocks.num_q_blocks
 
     @pl.when(t == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    relevant = _block_relevant(qi, kj, block_q=block_q, block_k=block_k,
-                               causal=causal, sliding_window=sliding_window)
-
-    @pl.when(relevant)
-    def _compute():
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = _pair_mask(q_pos, k_pos, q_len=q_len, kv_len=kv_len,
-                          causal=causal, sliding_window=sliding_window)
+    def _compute(masked: bool):
+        mask = blocks.mask(qi, kj) if masked else None
         q = q_ref[0].astype(jnp.float32)
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
@@ -379,6 +496,8 @@ def _dkv_kernel(
             p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         dk_scr[...] = dk_scr[...] + scale * jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    _run_blocks(blocks, qi, kj, _compute)
 
     @pl.when(t == group_steps - 1)
     def _finalize():
@@ -400,10 +519,11 @@ def _bwd_impl(
     B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
     G = Hq // Hkv
-    block_q = min(block_q, S)
-    block_k = min(block_k, T)
-    S_pad = -(-S // block_q) * block_q
-    T_pad = -(-T // block_k) * block_k
+    blocks = _Blocks.of(S, T, block_q, block_k, causal, sliding_window)
+    block_q, block_k = blocks.block_q, blocks.block_k
+    num_q_blocks, num_kv_blocks = blocks.num_q_blocks, blocks.num_kv_blocks
+    S_pad = num_q_blocks * block_q
+    T_pad = num_kv_blocks * block_k
 
     def pad_s(x):
         return jnp.pad(x, ((0, 0), (0, S_pad - S), (0, 0), (0, 0))) \
@@ -430,35 +550,23 @@ def _bwd_impl(
     lse_h = jnp.broadcast_to(lse_h[..., None], (*lse_h.shape, _LANES))
     delta = jnp.broadcast_to(delta[..., None], (*delta.shape, _LANES))
 
-    num_q_blocks = S_pad // block_q
-    num_kv_blocks = T_pad // block_k
-
     def q_index(bh, qi, kj):
         return (bh, qi, 0)
 
-    def kv_index(bh, qi, kj):
-        b, h = bh // Hq, bh % Hq
-        return (b * Hkv + h // G, kj, 0)
+    kv_index = _kv_index_map(blocks, Hq, Hkv)
 
-    def lse_index(bh, qi, kj):
-        return (bh, qi, 0)
-
-    common = dict(
-        block_q=block_q, block_k=block_k, q_len=S, kv_len=T,
-        causal=causal, sliding_window=sliding_window,
-        logit_softcap=logit_softcap, scale=scale,
-    )
+    common = dict(blocks=blocks, logit_softcap=logit_softcap, scale=scale)
 
     dq_call = pl.pallas_call(
-        functools.partial(_dq_kernel, num_kv_blocks=num_kv_blocks, **common),
+        functools.partial(_dq_kernel, **common),
         grid=(B * Hq, num_q_blocks, num_kv_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_index),
             pl.BlockSpec((1, block_k, D), kv_index),
             pl.BlockSpec((1, block_k, D), kv_index),
             pl.BlockSpec((1, block_q, D), q_index),
-            pl.BlockSpec((1, block_q, _LANES), lse_index),
-            pl.BlockSpec((1, block_q, _LANES), lse_index),
+            pl.BlockSpec((1, block_q, _LANES), q_index),
+            pl.BlockSpec((1, block_q, _LANES), q_index),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), q_index),
         out_shape=jax.ShapeDtypeStruct((B * Hq, S_pad, D), q.dtype),
@@ -477,25 +585,18 @@ def _bwd_impl(
     def kv_self_index(bhkv, kj, t):
         return (bhkv, kj, 0)
 
-    def q_group_index(bhkv, kj, t):
-        row = (bhkv // Hkv) * Hq + (bhkv % Hkv) * G + t // num_q_blocks
-        return (row, t % num_q_blocks, 0)
-
-    def lse_group_index(bhkv, kj, t):
-        row = (bhkv // Hkv) * Hq + (bhkv % Hkv) * G + t // num_q_blocks
-        return (row, t % num_q_blocks, 0)
+    q_group_index = _q_group_index_map(blocks, Hq, Hkv)
 
     dkv_call = pl.pallas_call(
-        functools.partial(_dkv_kernel, num_q_blocks=num_q_blocks,
-                          group_steps=G * num_q_blocks, **common),
+        functools.partial(_dkv_kernel, group_steps=G * num_q_blocks, **common),
         grid=(B * Hkv, num_kv_blocks, G * num_q_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_group_index),
             pl.BlockSpec((1, block_k, D), kv_self_index),
             pl.BlockSpec((1, block_k, D), kv_self_index),
             pl.BlockSpec((1, block_q, D), q_group_index),
-            pl.BlockSpec((1, block_q, _LANES), lse_group_index),
-            pl.BlockSpec((1, block_q, _LANES), lse_group_index),
+            pl.BlockSpec((1, block_q, _LANES), q_group_index),
+            pl.BlockSpec((1, block_q, _LANES), q_group_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), kv_self_index),

@@ -16,7 +16,8 @@ from hypothesis import strategies as st
 
 from repro.kernels import ops, ref
 from repro.kernels.registry import KernelConfig
-from repro.kernels.flash_attention import flash_attention, flash_attention_forward
+from repro.kernels.flash_attention import (
+    block_census, flash_attention, flash_attention_forward)
 from repro.kernels.rmsnorm import rmsnorm_forward
 
 
@@ -37,13 +38,30 @@ SHAPE_SWEEP = [
     (1, 256, 8, 2, 128, 128, 64),   # GQA 4:1, MXU-aligned D
 ]
 
+# The three block classes (empty, partial, full pairs), with blocks shaped as
+# the v5e table's (256, 512) scaled down and the cell's GQA 6:1. Appended
+# after SHAPE_SWEEP's cases, which keep their ids: causal, no window.
+# B, S, Hq, Hkv, D, block_q, block_k, causal, window
+BLOCK_CLASS_SWEEP = [
+    (1, 512, 6, 1, 128, 64, 128, True, None),  # 12 empty, 8 partial, 12 full
+    (1, 500, 6, 1, 64, 64, 128, True, None),   # padded S and T: tails partial
+    (1, 512, 6, 1, 64, 64, 128, True, 200),    # window edges cut blocks
+    (1, 512, 6, 1, 64, 64, 128, True, 256),    # window on block edges
+    (1, 512, 6, 1, 64, 64, 128, False, None),  # non-causal: every pair full
+    (1, 500, 2, 1, 64, 64, 128, False, 200),   # non-causal window, padded
+]
+FLASH_CASES = [pytest.param(*case, True, None, id="-".join(map(str, case)))
+               for case in SHAPE_SWEEP] + BLOCK_CLASS_SWEEP
 
-@pytest.mark.parametrize("B,S,Hq,Hkv,D,bq,bk", SHAPE_SWEEP)
-def test_flash_matches_ref_causal(B, S, Hq, Hkv, D, bq, bk):
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,bq,bk,causal,window", FLASH_CASES)
+def test_flash_matches_ref_causal(B, S, Hq, Hkv, D, bq, bk, causal, window):
     q, k, v = _mk_qkv(jax.random.PRNGKey(0), B, S, S, Hq, Hkv, D)
-    out = flash_attention_forward(q, k, v, causal=True, block_q=bq, block_k=bk,
-                                  interpret=True)
-    expect = ref.reference_attention(q, k, v, causal=True)
+    out = flash_attention_forward(q, k, v, causal=causal,
+                                  sliding_window=window, block_q=bq,
+                                  block_k=bk, interpret=True)
+    expect = ref.reference_attention(q, k, v, causal=causal,
+                                     sliding_window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=2e-5, rtol=2e-5)
 
@@ -94,6 +112,64 @@ def test_flash_property_sweep(B, S, heads, D, causal):
                                atol=3e-5, rtol=3e-5)
 
 
+def test_block_census_of_the_training_cell():
+    # 4096-token rows under the v5e table's (256, 512): 16 x 8 pairs a head.
+    assert block_census(4096, 4096, 256, 512, causal=True) == {
+        "empty": 56, "partial": 16, "full": 56}
+
+
+@given(
+    st.integers(1, 300),                   # S
+    st.integers(1, 300),                   # T
+    st.sampled_from([8, 16, 32, 64]),      # block_q
+    st.sampled_from([8, 16, 64, 128]),     # block_k
+    st.booleans(),                         # causal
+    st.one_of(st.just(None), st.integers(1, 300)),  # window
+)
+@settings(max_examples=50, deadline=None)
+def test_block_census_matches_element_mask(S, T, bq, bk, causal, window):
+    """A pair is full iff every score in it is kept, empty iff none is."""
+    bq_, bk_ = min(bq, S), min(bk, T)  # as the kernels clamp them
+    nq, nk = -(-S // bq_), -(-T // bk_)
+    r = np.arange(nq * bq_)[:, None]
+    c = np.arange(nk * bk_)[None, :]
+    keep = (r < S) & (c < T)
+    if causal:
+        keep &= c <= r
+    if window is not None:
+        keep &= c > r - window
+    pairs = keep.reshape(nq, bq_, nk, bk_)
+    some, every = pairs.any(axis=(1, 3)), pairs.all(axis=(1, 3))
+    assert block_census(S, T, bq, bk, causal, window) == {
+        "empty": int((~some).sum()), "partial": int((some & ~every).sum()),
+        "full": int(every.sum())}
+
+
+def _copies(index_map, steps):
+    """Block copies of a sequential grid walk: one at the start, and one at
+    each step whose block index differs from the step before."""
+    idx = [tuple(int(i) for i in index_map(*step)) for step in steps]
+    return 1 + sum(a != b for a, b in zip(idx, idx[1:]))
+
+
+def test_empty_steps_fetch_nothing():
+    """At the training cell's shapes the K/V index map (forward, dQ) and the
+    q-side one (dKV) copy at most one block per computing step of a head,
+    and none for the 56 empty pairs (every step copied before: 128 and
+    768). K/V: 72 computing steps, less two where q rows 1 and 2 start on
+    kv block 0, on which the row before ended."""
+    from repro.kernels.flash_attention import (
+        _Blocks, _kv_index_map, _q_group_index_map)
+
+    blocks = _Blocks.of(4096, 4096, 256, 512, True, None)
+    nq, nk, G = blocks.num_q_blocks, blocks.num_kv_blocks, 6
+    kv = _copies(_kv_index_map(blocks, 12, 2),
+                 [(0, qi, kj) for qi in range(nq) for kj in range(nk)])
+    q = _copies(_q_group_index_map(blocks, 12, 2),
+                [(0, kj, t) for kj in range(nk) for t in range(G * nq)])
+    assert (kv, q) == (16 + 56 - 2, G * (16 + 56))
+
+
 # ------------------------ flash attention backward ---------------------------
 #
 # The recompute-based custom_vjp (dKV + dQ Pallas passes) must match
@@ -122,9 +198,10 @@ def _grad_parity(B, S, Hq, Hkv, D, *, causal=True, window=None, cap=None,
                                    atol=tol, rtol=tol, err_msg=name)
 
 
-@pytest.mark.parametrize("B,S,Hq,Hkv,D,bq,bk", SHAPE_SWEEP)
-def test_flash_backward_matches_ref_grads(B, S, Hq, Hkv, D, bq, bk):
-    _grad_parity(B, S, Hq, Hkv, D, bq=bq, bk=bk)
+@pytest.mark.parametrize("B,S,Hq,Hkv,D,bq,bk,causal,window", FLASH_CASES)
+def test_flash_backward_matches_ref_grads(B, S, Hq, Hkv, D, bq, bk, causal,
+                                          window):
+    _grad_parity(B, S, Hq, Hkv, D, causal=causal, window=window, bq=bq, bk=bk)
 
 
 def test_flash_backward_gqa_sliding_window():

@@ -107,6 +107,23 @@ def test_flash_attention_forward_backward_compiles(one_chip):
     _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *QKV)
 
 
+@pytest.mark.parametrize("seq,window", [
+    (2048, 1000),  # window edges cut blocks: all three block classes
+    (1000, None),  # padded length: the tail pairs are partial
+])
+def test_flash_attention_block_classes_compile(one_chip, seq, window):
+    """The empty-step index maps and the partial and full bodies lower for
+    Mosaic, forward and backward."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, sliding_window=window, block_q=256,
+                              block_k=512)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+             ((4, seq, HQ, D), BF16), ((4, seq, HKV, D), BF16),
+             ((4, seq, HKV, D), BF16))
+
+
 @pytest.mark.parametrize("rows", [SLOTS, 4 * 1024])
 def test_rmsnorm_compiles(one_chip, rows):
     _compile(lambda x, s: rmsnorm_forward(x, s), one_chip,
