@@ -233,6 +233,9 @@ class ArchSpec:
     make_smoke: Any  # () -> reduced model config
     vocab_size: int
     model_dim: int
+    # () -> one chip's share of a stated deployment at published widths
+    # (depth, experts held, vocabulary slice); None where none is defined.
+    make_share: Optional[Any] = None
     modality: str = "text"
     # Shapes this arch runs, with skip reasons for the rest.
     skip_shapes: Dict[str, str] = dataclasses.field(default_factory=dict)

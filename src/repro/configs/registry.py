@@ -23,9 +23,12 @@ _ARCH_MODULES = {
     "internlm2-1.8b": "repro.configs.internlm2_1_8b",
     # Paper's own eval model (extra, not in the assigned pool):
     "llama2-7b": "repro.configs.llama2_7b",
+    # Benchmark configurations beyond the assigned pool:
+    "moonlight-16b-a3b": "repro.configs.moonlight_16b_a3b",
 }
+_EXTRA_ARCHS = ("llama2-7b", "moonlight-16b-a3b")
 
-ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES if a != "llama2-7b"]
+ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES if a not in _EXTRA_ARCHS]
 ALL_ARCHS: List[str] = list(_ARCH_MODULES)
 SHAPE_NAMES: List[str] = list(SHAPES)
 

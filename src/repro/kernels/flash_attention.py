@@ -7,7 +7,9 @@ running max / denominator / accumulator in VMEM scratch — the TPU analogue of
 a CUDA thread-block's registers/SMEM. Block shapes default to (128, 128) to
 align with the 128x128 MXU tile and 8x128 VREG lanes.
 
-Supports: causal masking, sliding windows, logit soft-capping, and GQA. The
+Supports: causal masking, sliding windows, logit soft-capping, GQA, and a
+value head dim Dv that differs from the q/k head dim D (latent attention:
+D = 192, Dv = 128). The
 forward and dQ grids run over query heads (B*Hq) and map each to its KV head
 in the BlockSpec index_map, so a KV block is fetched once per query head, not
 once per group; the dKV grid runs over KV heads and streams its group's
@@ -241,12 +243,12 @@ def _q_group_index_map(blocks: _Blocks, Hq: int, Hkv: int):
 def _fwd_kernel(
     q_ref,  # (1, block_q, D)
     k_ref,  # (1, block_k, D)
-    v_ref,  # (1, block_k, D)
-    o_ref,  # (1, block_q, D)
+    v_ref,  # (1, block_k, Dv)
+    o_ref,  # (1, block_q, Dv)
     lse_ref,  # (1, block_q, _LANES) f32, lane-broadcast
     m_scr,  # (block_q, _LANES) f32
     l_scr,  # (block_q, _LANES) f32
-    acc_scr,  # (block_q, D) f32
+    acc_scr,  # (block_q, Dv) f32
     *,
     blocks: _Blocks,
     logit_softcap: Optional[float],
@@ -288,7 +290,7 @@ def _fwd_kernel(
             alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
 
         l_new = alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0].astype(jnp.float32)  # (bk, Dv)
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + pv
@@ -314,7 +316,7 @@ def _fwd_kernel(
 def _fwd_impl(
     q: jax.Array,  # (B, S, Hq, D)
     k: jax.Array,  # (B, T, Hkv, D)
-    v: jax.Array,
+    v: jax.Array,  # (B, T, Hkv, Dv)
     *,
     causal: bool,
     sliding_window: Optional[int],
@@ -324,9 +326,10 @@ def _fwd_impl(
     block_k: int,
     interpret: bool,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Returns (out (B, S, Hq, D), lse (B, Hq, S) fp32)."""
+    """Returns (out (B, S, Hq, Dv), lse (B, Hq, S) fp32)."""
     B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     assert Hq % Hkv == 0
     blocks = _Blocks.of(S, T, block_q, block_k, causal, sliding_window)
     block_q, block_k = blocks.block_q, blocks.block_k
@@ -343,7 +346,7 @@ def _fwd_impl(
     # Head-major layout: (B*H, S, D).
     qh = q.transpose(0, 2, 1, 3).reshape(B * Hq, S_pad, D)
     kh = k.transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, D)
-    vh = v.transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, D)
+    vh = v.transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, Dv)
 
     grid = (B * Hq, blocks.num_q_blocks, blocks.num_kv_blocks)
 
@@ -365,20 +368,20 @@ def _fwd_impl(
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_index),
             pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
+            pl.BlockSpec((1, block_k, Dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), q_index),
+            pl.BlockSpec((1, block_q, Dv), q_index),
             pl.BlockSpec((1, block_q, _LANES), q_index),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * Hq, S_pad, D), q.dtype),
+            jax.ShapeDtypeStruct((B * Hq, S_pad, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * Hq, S_pad, _LANES), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -389,7 +392,7 @@ def _fwd_impl(
     with jax.named_scope("flash_fwd"):
         out, lse = fwd(qh, kh, vh)
 
-    out = out.reshape(B, Hq, S_pad, D).transpose(0, 2, 1, 3)[:, :S]
+    out = out.reshape(B, Hq, S_pad, Dv).transpose(0, 2, 1, 3)[:, :S]
     lse = lse[..., 0].reshape(B, Hq, S_pad)[:, :, :S]
     return out, lse
 
@@ -465,7 +468,7 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk_ref, dv_ref,
-    dk_scr, dv_scr,  # (block_k, D) f32
+    dk_scr, dv_scr,  # (block_k, D), (block_k, Dv) f32
     *,
     blocks: _Blocks,
     group_steps: int,  # G * num_q_blocks
@@ -518,6 +521,7 @@ def _bwd_impl(
 ):
     B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     G = Hq // Hkv
     blocks = _Blocks.of(S, T, block_q, block_k, causal, sliding_window)
     block_q, block_k = blocks.block_q, blocks.block_k
@@ -534,10 +538,10 @@ def _bwd_impl(
             if T_pad != T else x
 
     qh = pad_s(q).transpose(0, 2, 1, 3).reshape(B * Hq, S_pad, D)
-    doh = pad_s(do).transpose(0, 2, 1, 3).reshape(B * Hq, S_pad, D)
-    oh = pad_s(out).transpose(0, 2, 1, 3).reshape(B * Hq, S_pad, D)
+    doh = pad_s(do).transpose(0, 2, 1, 3).reshape(B * Hq, S_pad, Dv)
+    oh = pad_s(out).transpose(0, 2, 1, 3).reshape(B * Hq, S_pad, Dv)
     kh = pad_t(k).transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, D)
-    vh = pad_t(v).transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, D)
+    vh = pad_t(v).transpose(0, 2, 1, 3).reshape(B * Hkv, T_pad, Dv)
 
     # delta_i = sum_d do_id * o_id (cheap elementwise preprocess, fp32).
     delta = jnp.sum(doh.astype(jnp.float32) * oh.astype(jnp.float32), axis=-1)
@@ -563,8 +567,8 @@ def _bwd_impl(
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_index),
             pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_k, D), kv_index),
-            pl.BlockSpec((1, block_q, D), q_index),
+            pl.BlockSpec((1, block_k, Dv), kv_index),
+            pl.BlockSpec((1, block_q, Dv), q_index),
             pl.BlockSpec((1, block_q, _LANES), q_index),
             pl.BlockSpec((1, block_q, _LANES), q_index),
         ],
@@ -593,22 +597,22 @@ def _bwd_impl(
         in_specs=[
             pl.BlockSpec((1, block_q, D), q_group_index),
             pl.BlockSpec((1, block_k, D), kv_self_index),
-            pl.BlockSpec((1, block_k, D), kv_self_index),
-            pl.BlockSpec((1, block_q, D), q_group_index),
+            pl.BlockSpec((1, block_k, Dv), kv_self_index),
+            pl.BlockSpec((1, block_q, Dv), q_group_index),
             pl.BlockSpec((1, block_q, _LANES), q_group_index),
             pl.BlockSpec((1, block_q, _LANES), q_group_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), kv_self_index),
-            pl.BlockSpec((1, block_k, D), kv_self_index),
+            pl.BlockSpec((1, block_k, Dv), kv_self_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, T_pad, D), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, T_pad, D), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, T_pad, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -621,7 +625,7 @@ def _bwd_impl(
 
     dq = dq.reshape(B, Hq, S_pad, D).transpose(0, 2, 1, 3)[:, :S]
     dk = dk.reshape(B, Hkv, T_pad, D).transpose(0, 2, 1, 3)[:, :T]
-    dv = dv.reshape(B, Hkv, T_pad, D).transpose(0, 2, 1, 3)[:, :T]
+    dv = dv.reshape(B, Hkv, T_pad, Dv).transpose(0, 2, 1, 3)[:, :T]
     return dq, dk, dv
 
 
@@ -665,7 +669,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention(
     q: jax.Array,  # (B, S, Hq, D)
     k: jax.Array,  # (B, T, Hkv, D)
-    v: jax.Array,
+    v: jax.Array,  # (B, T, Hkv, Dv)
     *,
     causal: bool = True,
     sliding_window: Optional[int] = None,

@@ -24,7 +24,7 @@ from repro.kernels.registry import (DEFAULT_CONFIG, KernelConfig,
                                     KernelFeatures)
 
 __all__ = ["flash_attention", "decode_attention", "paged_gather_kv",
-           "rmsnorm", "wkv6", "wkv6_decode"]
+           "rmsnorm", "wkv6", "wkv6_decode", "grouped_matmul"]
 
 
 def _same_positions(q_positions, k_positions) -> bool:
@@ -233,3 +233,15 @@ def wkv6(r, k, v, w, u, state=None, *,
     spec = registry.resolve_backend("wkv6", feats, kernel)
     return spec.fn(r, k, v, w, u, state,
                    cfg=kernel)
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,
+                   kernel: Optional[KernelConfig] = None) -> jax.Array:
+    """Grouped matrix product (``moe.gmm``): rows ``lhs`` (m, k) in group
+    order, one (k, n) matrix per group in ``rhs``, ``group_sizes`` (groups,)
+    int32 summing to at most m. Rows past the groups are undefined."""
+    kernel = kernel if kernel is not None else DEFAULT_CONFIG
+    feats = KernelFeatures(platform=registry.current_platform(),
+                           dtype=str(lhs.dtype))
+    spec = registry.resolve_backend("moe.gmm", feats, kernel)
+    return spec.fn(lhs, rhs, group_sizes, cfg=kernel)

@@ -107,7 +107,7 @@ def reference_attention(
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    out = out.reshape(B, S, Hq, D)
+    out = out.reshape(B, S, Hq, v.shape[-1])
     return out.astype(q.dtype)
 
 
@@ -169,7 +169,7 @@ def blockwise_attention(
     _, out = jax.lax.scan(lambda c, xs: (c, one_chunk(xs)), 0,
                           (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(qp, 1, 0)),
                           unroll=unroll)
-    out = jnp.moveaxis(out, 0, 1).reshape(B, S, Hq, D)
+    out = jnp.moveaxis(out, 0, 1).reshape(B, S, Hq, v.shape[-1])
     return out
 
 

@@ -22,6 +22,7 @@ Ops and backends registered here:
   rmsnorm            pallas > pallas:interpret > ref
   wkv6               pallas > pallas:interpret > ref
   wkv6.decode        ref (O(1) recurrent step)
+  moe.gmm            pallas > pallas:interpret > ref (ragged_dot)
 
 ``ref`` backends are pure-XLA and eligible everywhere; they are also the
 numerical oracles (``repro.kernels.ref``). ``pallas:interpret`` runs the
@@ -469,6 +470,7 @@ def _register_builtin_specs() -> None:
         flash_decode_forward,
         paged_flash_decode_forward,
     )
+    from repro.kernels.gmm import grouped_matmul
     from repro.kernels.rmsnorm import rmsnorm_forward
     from repro.kernels.wkv6 import wkv6_forward
 
@@ -647,6 +649,31 @@ def _register_builtin_specs() -> None:
         supports=_chain(_interpret_gate, wkv_caps)))
     register(KernelSpec(
         op="wkv6", backend="ref", fn=_wkv6_ref,
+        platforms=("*",), priority=0))
+
+    # ---- moe.gmm --------------------------------------------------------
+    # fn(lhs, rhs, group_sizes, *, cfg): rows past the groups are undefined.
+
+    def _gmm_pallas(interpret):
+        def fn(lhs, rhs, group_sizes, *, cfg):
+            del cfg
+            return grouped_matmul(lhs, rhs, group_sizes, interpret=interpret)
+
+        return fn
+
+    def _gmm_ref(lhs, rhs, group_sizes, *, cfg):
+        del cfg
+        return jax.lax.ragged_dot(lhs, rhs.astype(lhs.dtype), group_sizes,
+                                  preferred_element_type=lhs.dtype)
+
+    register(KernelSpec(
+        op="moe.gmm", backend="pallas", fn=_gmm_pallas(False),
+        platforms=("tpu",), priority=100, supports=_pallas_gate))
+    register(KernelSpec(
+        op="moe.gmm", backend="pallas:interpret", fn=_gmm_pallas(True),
+        platforms=("*",), priority=90, supports=_interpret_gate))
+    register(KernelSpec(
+        op="moe.gmm", backend="ref", fn=_gmm_ref,
         platforms=("*",), priority=0))
 
 

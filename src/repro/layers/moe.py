@@ -1,6 +1,15 @@
 """Mixture-of-Experts FFN — the paper's flagship drop-in replacement (§2.1).
 
-GSPMD/GShard-style capacity-based token-choice top-k routing with einsum
+Two expert layers, chosen by the model's configuration:
+
+* :class:`MoELayer` (mixtral, arctic, jamba): GShard softmax routing with a
+  capacity, einsum dispatch/combine; tokens over capacity are dropped.
+* :class:`DroplessMoE` (DeepSeek-V3 / Moonlight): sigmoid routing with a
+  selection bias, no capacity, shared experts, and a layer told which
+  experts it holds: it routes over all of them and computes only its own
+  experts' part, through a grouped matrix product.
+
+MoELayer is GSPMD/GShard-style capacity-based token-choice top-k routing with einsum
 dispatch/combine, designed for expert parallelism over the "model" (or a
 dedicated "expert") mesh axis. The load-balance and router-z auxiliary
 losses are emitted through the InvocationContext (``add_module_output``),
@@ -26,11 +35,14 @@ from repro.core.config import (
     maybe_set,
 )
 from repro.core.utils import PartitionSpecLike, remat_name
-from repro.layers.base import BaseLayer, ParameterSpec, fan_in_init, normal_init
+from repro.kernels import ops as kernel_ops
+from repro.layers.base import (BaseLayer, KernelConfig, ParameterSpec,
+                               fan_in_init, normal_init, zeros_init)
 from repro.layers.basic import get_activation
 from repro.layers.ffn import FeedForward
 
-__all__ = ["MoELayer", "ResidualMoE", "TopKRouter"]
+__all__ = ["DroplessMoE", "MoELayer", "ResidualMoE", "SigmoidRouter",
+           "TopKRouter"]
 
 
 class TopKRouter(BaseLayer):
@@ -247,3 +259,188 @@ class ResidualMoE(BaseLayer):
 
     def forward(self, x: jax.Array) -> jax.Array:
         return self.dense(x) + self.moe(x)
+
+
+class SigmoidRouter(BaseLayer):
+    """DeepSeek-V3 routing with one expert group (``noaux_tc``,
+    ``n_group = topk_group = 1``) [arXiv:2412.19437, §2.1.2]:
+
+      s     = sigmoid(x W)                      over every expert, fp32
+      top   = top_k(s + b)                      b: selection only
+      gates = s[top] / sum(s[top]) * routed_scale
+
+    ``b`` (the correction bias) is a parameter that takes no gradient: after
+    each training step it moves by ``bias_rate * sign(mean load - load)``,
+    emitted as a state update that the train step folds back into the
+    params. ``balance_weight`` (alpha) weighs the sequence-wise balance
+    loss: alpha * sum_i f_i P_i per sequence, f_i = E / (K S) * (selections
+    of expert i), P_i = mean over the sequence of s_i / sum_j s_j; emitted
+    as an ``aux_loss`` and averaged over sequences.
+    """
+
+    @config_class
+    class Config(BaseLayer.Config):
+        input_dim: Required[int] = REQUIRED
+        num_experts: Required[int] = REQUIRED
+        top_k: Required[int] = REQUIRED
+        routed_scale: float = 1.0
+        balance_weight: float = 0.0
+        bias_rate: float = 0.0
+
+    def _create_layer_parameter_specs(self):
+        cfg = self.config
+        return {
+            "weight": ParameterSpec(
+                shape=(cfg.input_dim, cfg.num_experts), dtype=jnp.float32,
+                initializer=fan_in_init(), mesh_axes=(None, None)),
+            "bias": ParameterSpec(
+                shape=(cfg.num_experts,), dtype=jnp.float32,
+                initializer=zeros_init(), mesh_axes=(None,),
+                weight_decay_scale=0.0),
+        }
+
+    def forward(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+        """(B, S, D) -> (experts (B, S, K) int32, gates (B, S, K) fp32)."""
+        cfg = self.config
+        E, K = cfg.num_experts, cfg.top_k
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32), self.state["weight"],
+            precision=jax.lax.Precision.HIGHEST))
+        bias = jax.lax.stop_gradient(self.state["bias"])
+        _, experts = jax.lax.top_k(scores + bias, K)
+        chosen = jnp.take_along_axis(scores, experts, axis=-1)
+        gates = chosen / jnp.sum(chosen, -1, keepdims=True) * cfg.routed_scale
+        # (B, E) selections per sequence.
+        counts = jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32),
+                         axis=(1, 2))
+        if cfg.balance_weight:
+            S = x.shape[1]
+            f = counts * (E / (K * S))
+            p = jnp.mean(scores / jnp.sum(scores, -1, keepdims=True), axis=1)
+            self.add_module_output(
+                "aux_loss", cfg.balance_weight * jnp.mean(jnp.sum(f * p, -1)))
+        if cfg.bias_rate and self.is_training:
+            load = jnp.sum(counts, axis=0)
+            self.add_state_update("bias", self.state["bias"] + cfg.bias_rate
+                                  * jnp.sign(jnp.mean(load) - load))
+        return experts, gates
+
+
+class DroplessMoE(BaseLayer):
+    """Drop-in FFN: routed experts without capacity, plus shared experts.
+
+    The layer holds experts ``[first, first + count)`` of ``num_experts``
+    (``held_experts``; None holds them all). It routes every token over all
+    experts and computes only its own experts' part of the result: on a
+    chip of an expert-parallel group, that part is what this chip adds, and
+    the absent experts' parts would come from the other chips. No token is
+    dropped. Each sequence's held assignments are sorted by expert and run
+    through the grouped matrix product (``moe.gmm``), one sequence at a
+    time so that one sequence's sorted rows are live at once:
+
+      y = sum over the held top-k of gate_i * FFN_i(x) + FFN_shared(x)
+
+    Scopes: ``router`` (the router module), ``moe.dispatch`` (sort and
+    gather into expert order), ``expert_gmm`` (the grouped products, forward
+    and backward), ``moe.combine`` (the weighted sum back in token order).
+    Summaries: ``held_assignments`` (rows the held experts computed) and
+    ``held_load_max_over_mean`` (over the held experts).
+    """
+
+    @config_class
+    class Config(BaseLayer.Config):
+        input_dim: Required[int] = REQUIRED
+        hidden_dim: Required[int] = REQUIRED
+        num_experts: Required[int] = REQUIRED
+        top_k: Required[int] = REQUIRED
+        # (first, count) of the experts this layer holds; None: all.
+        held_experts: Optional[Tuple[int, int]] = None
+        router: SigmoidRouter.Config = SigmoidRouter.Config()
+        # The shared experts as one gated FFN (None: no shared expert).
+        shared: Optional[FeedForward.Config] = None
+        kernel: KernelConfig = KernelConfig()
+
+    def __init__(self, cfg, *, parent=None):
+        super().__init__(cfg, parent=parent)
+        cfg = self.config
+        if cfg.held_experts is None:
+            cfg.set(held_experts=(0, cfg.num_experts))
+        first, count = cfg.held_experts
+        if not 0 <= first < first + count <= cfg.num_experts:
+            raise ValueError(f"held_experts {cfg.held_experts} outside "
+                             f"{cfg.num_experts} experts")
+        router = cfg.router.clone().set(
+            input_dim=cfg.input_dim, num_experts=cfg.num_experts,
+            top_k=cfg.top_k)
+        self._add_child("router", maybe_set(router,
+                                            dtype_policy=cfg.dtype_policy))
+        if cfg.shared is not None:
+            self._add_child("shared_experts", maybe_set(
+                cfg.shared.clone().set(input_dim=cfg.input_dim),
+                dtype_policy=cfg.dtype_policy))
+
+    # SwiGLU experts: wi_0 the linear branch, wi_1 the SiLU branch, wo down.
+    _WEIGHTS = ("wi_0", "wi_1", "wo")
+
+    def _create_layer_parameter_specs(self):
+        cfg = self.config
+        n, D, H = cfg.held_experts[1], cfg.input_dim, cfg.hidden_dim
+        up = ((n, D, H), (None, None, "model"))
+        down = ((n, H, D), (None, "model", None))
+        return {name: ParameterSpec(
+            shape=shape, dtype=cfg.param_dtype,
+            initializer=fan_in_init(fan_in_axes=(-2,)), mesh_axes=axes)
+            for name, (shape, axes) in zip(self._WEIGHTS, (up, up, down))}
+
+    def _sequence(self, weights, x, local, gates):
+        """One sequence's routed part: x (S, D), local (S, K) held expert
+        index or ``count`` (not held), gates (S, K). Returns (y (S, D) fp32,
+        rows per held expert (count,) int32)."""
+        cfg = self.config
+        count = cfg.held_experts[1]
+        S, K = local.shape
+        with jax.named_scope("moe.dispatch"):
+            flat = local.reshape(-1)
+            # Stable: held experts' rows in expert order, then the rest.
+            order = jnp.argsort(flat, stable=True)
+            sizes = jnp.bincount(flat, length=count + 1)[:count].astype(
+                jnp.int32)
+            # Rows past the held ones are never computed: the grouped
+            # product leaves them undefined, so every use is masked.
+            held = (jnp.arange(S * K) < jnp.sum(sizes))[:, None]
+            rows = jnp.where(held, x[order // K], 0)
+
+        def gmm(lhs, name):
+            with jax.named_scope("expert_gmm"):
+                out = kernel_ops.grouped_matmul(lhs, weights[name], sizes,
+                                                kernel=self.kernel_config)
+            return jnp.where(held, out, 0)
+
+        out = gmm(gmm(rows, "wi_0") * jax.nn.silu(gmm(rows, "wi_1")), "wo")
+        with jax.named_scope("moe.combine"):
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(S * K, dtype=order.dtype))
+            gates = jnp.where(local < count, gates, 0.0)
+            y = jnp.einsum("skd,sk->sd",
+                           out[back].reshape(S, K, -1).astype(jnp.float32),
+                           gates)
+        return y, sizes
+
+    def forward(self, x: jax.Array) -> jax.Array:
+        cfg = self.config
+        x = self._to_compute(x)
+        first, count = cfg.held_experts
+        experts, gates = self.router(x)
+        held = (experts >= first) & (experts < first + count)
+        local = jnp.where(held, experts - first, count)
+        weights = {k: self.state[k] for k in self._WEIGHTS}
+        seq = jax.checkpoint(self._sequence, prevent_cse=False)
+        y, sizes = jax.lax.map(lambda a: seq(weights, *a), (x, local, gates))
+        load = jnp.sum(sizes, axis=0).astype(jnp.float32)
+        self.add_summary("held_assignments", jnp.sum(load))
+        self.add_summary("held_load_max_over_mean",
+                         jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0))
+        y = y.astype(x.dtype)
+        if cfg.shared is not None:
+            y = y + self.shared_experts(x)
+        return remat_name(y, "ffn_out")

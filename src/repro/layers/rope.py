@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from repro.core.config import REQUIRED, Required, config_class
 from repro.layers.base import BaseLayer
 
-__all__ = ["BaseRotaryEmbedding", "RotaryEmbedding", "LinearScaledRotaryEmbedding"]
+__all__ = ["BaseRotaryEmbedding", "RotaryEmbedding", "PairRotaryEmbedding",
+           "LinearScaledRotaryEmbedding"]
 
 
 class BaseRotaryEmbedding(BaseLayer):
@@ -70,6 +71,18 @@ class RotaryEmbedding(BaseRotaryEmbedding):
             return _apply_half_rotation(x, sin, cos)
         x_rot, x_pass = x[..., :rot_dim], x[..., rot_dim:]
         return jnp.concatenate([_apply_half_rotation(x_rot, sin, cos), x_pass], axis=-1)
+
+
+class PairRotaryEmbedding(RotaryEmbedding):
+    """DeepSeek's layout: rotates the adjacent pairs (x[2i], x[2i+1]) by
+    position * theta^(-2i/dim) and returns them de-interleaved, rotated
+    first halves then second halves, as DeepSeek-V3's
+    ``apply_rotary_pos_emb`` does (it views the last dim as (dim/2, 2) and
+    transposes before the half rotation)."""
+
+    def apply(self, x: jax.Array, positions: jax.Array) -> jax.Array:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+        return super().apply(x, positions)
 
 
 class LinearScaledRotaryEmbedding(RotaryEmbedding):

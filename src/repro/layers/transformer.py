@@ -348,7 +348,10 @@ class Repeat(BaseLayer):
             from repro.trainer.remat import policy_from_spec
 
             policy = policy_from_spec(cfg.remat_policy)
-            body = jax.checkpoint(body, policy=policy, prevent_cse=False)
+            # The scan keeps XLA from merging the recomputation with the
+            # forward pass (CSE), except where XLA removes a one-trip loop.
+            body = jax.checkpoint(body, policy=policy,
+                                  prevent_cse=cfg.num_layers == 1)
 
         xs: Dict[str, Any] = {"params": params}
         if keys is not None:

@@ -108,13 +108,15 @@ def constrain_tree(tree: Any, specs: Optional[Any]) -> Any:
 
 def scalar_summaries(col) -> Dict[str, Any]:
     """The exportable slice of an ``OutputCollection``: scalar summaries
-    (loss/accuracy, MoE load-balance stats, per-layer norms) keyed by module
-    path. Non-scalar summaries (activation histograms etc.) stay in the
-    collection for callers that want them — routing tensors out of every
-    step would bloat the jitted step's outputs for no telemetry gain."""
+    (loss/accuracy, MoE load-balance stats and counters, per-layer norms)
+    keyed by module path, a layer's scalar stacked into one value per layer
+    by a scanned ``Repeat``. Larger summaries (activation histograms etc.)
+    stay in the collection for callers that want them — routing tensors out
+    of every step would bloat the jitted step's outputs for no telemetry
+    gain."""
     out = {}
     for k, v in col.summaries.items():
-        if isinstance(v, (int, float)) or getattr(v, "shape", None) == ():
+        if isinstance(v, (int, float)) or getattr(v, "ndim", None) in (0, 1):
             out[k] = v
     return out
 

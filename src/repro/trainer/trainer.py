@@ -57,13 +57,19 @@ class WatchdogTimeout(RuntimeError):
 
 def _flatten_metrics(metrics: Dict[str, Any]) -> Dict[str, float]:
     """Step metrics -> flat {name: float}. Nested dicts (the routed
-    ``summaries`` subtree) flatten as ``summaries/<module-path>``. Forces a
-    host transfer, so call only at the logging cadence."""
+    ``summaries`` subtree) flatten as ``summaries/<module-path>``, and a
+    per-layer summary of a scanned stack as ``summaries/<module-path>/<i>``.
+    Forces a host transfer, so call only at the logging cadence."""
     flat: Dict[str, float] = {}
     for k, v in metrics.items():
         if isinstance(v, dict):
             for sk, sv in v.items():
-                flat[f"{k}/{sk}"] = float(sv)
+                sv = np.asarray(sv)
+                if sv.ndim:
+                    for i, x in enumerate(sv):
+                        flat[f"{k}/{sk}/{i}"] = float(x)
+                else:
+                    flat[f"{k}/{sk}"] = float(sv)
         else:
             flat[k] = float(v)
     return flat

@@ -69,9 +69,11 @@ def test_auto_resolution_matrix(op, platform, overrides, expected):
 def test_registered_backends_priority_order():
     assert reg.registered_backends("attention.fwd") == [
         "pallas", "pallas:interpret", "blockwise", "ref"]
+    assert reg.registered_backends("moe.gmm") == [
+        "pallas", "pallas:interpret", "ref"]
     assert set(reg.registered_ops()) == {
         "attention.fwd", "attention.decode", "rmsnorm", "wkv6",
-        "wkv6.decode"}
+        "wkv6.decode", "moe.gmm"}
 
 
 # --------------------- rejection reasons / errors ----------------------------

@@ -21,11 +21,11 @@ from repro.kernels.flash_attention import (
 from repro.kernels.rmsnorm import rmsnorm_forward
 
 
-def _mk_qkv(key, B, S, T, Hq, Hkv, D, dtype=jnp.float32):
+def _mk_qkv(key, B, S, T, Hq, Hkv, D, dtype=jnp.float32, Dv=None):
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, S, Hq, D), dtype)
     k = jax.random.normal(ks[1], (B, T, Hkv, D), dtype)
-    v = jax.random.normal(ks[2], (B, T, Hkv, D), dtype)
+    v = jax.random.normal(ks[2], (B, T, Hkv, Dv or D), dtype)
     return q, k, v
 
 
@@ -178,9 +178,10 @@ def test_empty_steps_fetch_nothing():
 
 
 def _grad_parity(B, S, Hq, Hkv, D, *, causal=True, window=None, cap=None,
-                 bq=64, bk=64, tol=3e-4, seed=0):
-    q, k, v = _mk_qkv(jax.random.PRNGKey(seed), B, S, S, Hq, Hkv, D)
-    do = jax.random.normal(jax.random.PRNGKey(seed + 1), q.shape)
+                 bq=64, bk=64, tol=3e-4, seed=0, Dv=None):
+    q, k, v = _mk_qkv(jax.random.PRNGKey(seed), B, S, S, Hq, Hkv, D, Dv=Dv)
+    do = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                           q.shape[:-1] + v.shape[-1:])
 
     def f_flash(q, k, v):
         return jnp.sum(do * flash_attention(
@@ -202,6 +203,40 @@ def _grad_parity(B, S, Hq, Hkv, D, *, causal=True, window=None, cap=None,
 def test_flash_backward_matches_ref_grads(B, S, Hq, Hkv, D, bq, bk, causal,
                                           window):
     _grad_parity(B, S, Hq, Hkv, D, causal=causal, window=window, bq=bq, bk=bk)
+
+
+# Latent attention's heads (Moonlight / DeepSeek-V3): q and k are 192 wide
+# (128 + 64 RoPE), v and the output 128, under causal, windowed and
+# non-causal masks; blocks as in BLOCK_CLASS_SWEEP, so all three block
+# classes occur.
+# B, S, Hq, Hkv, bq, bk, causal, window
+MLA_CASES = [
+    (1, 512, 2, 2, 64, 128, True, None),
+    (1, 512, 2, 2, 64, 128, True, 200),
+    (1, 500, 2, 2, 64, 128, False, None),
+]
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,bq,bk,causal,window", MLA_CASES)
+def test_flash_mla_head_dims_match_ref(B, S, Hq, Hkv, bq, bk, causal,
+                                       window):
+    q, k, v = _mk_qkv(jax.random.PRNGKey(3), B, S, S, Hq, Hkv, 192, Dv=128)
+    out = flash_attention_forward(q, k, v, causal=causal,
+                                  sliding_window=window, block_q=bq,
+                                  block_k=bk, interpret=True)
+    expect = ref.reference_attention(q, k, v, causal=causal,
+                                     sliding_window=window)
+    assert out.shape == (B, S, Hq, 128)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("B,S,Hq,Hkv,bq,bk,causal,window", MLA_CASES)
+def test_flash_mla_head_dims_backward(B, S, Hq, Hkv, bq, bk, causal, window):
+    # 192-wide scores sum more terms than the 128-wide cases: same
+    # tolerance as the padded case.
+    _grad_parity(B, S, Hq, Hkv, 192, Dv=128, causal=causal, window=window,
+                 bq=bq, bk=bk, tol=5e-4)
 
 
 def test_flash_backward_gqa_sliding_window():

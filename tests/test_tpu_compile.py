@@ -4,8 +4,9 @@ The TPU compiler is installed even where no chip is attached: it compiles
 for a described v5e topology, and refuses what the chip would refuse — a
 block shape the tiling does not allow, too much fast memory, an op Mosaic
 cannot lower. Interpret-mode tests cannot see any of that. Widths are the
-published ones: qwen2-1.5b (12 query / 2 KV heads of 128, d=1536) and
-rwkv6-7b (64 heads of 64).
+published ones: qwen2-1.5b (12 query / 2 KV heads of 128, d=1536),
+rwkv6-7b (64 heads of 64) and moonlight-16b-a3b (latent attention, 16 heads
+of 192 / 128; 8 held experts of 2048 x 1408).
 
 The topology is described inside a module fixture, never at import time:
 only the worker that runs this file loads the TPU library.
@@ -26,6 +27,7 @@ from repro.kernels.wkv6 import wkv6_forward
 BF16 = jnp.bfloat16
 HQ, HKV, D, DIM = 12, 2, 128, 1536  # qwen2-1.5b
 SLOTS, PAGES, PAGE, TABLE = 8, 545, 16, 68  # 8 x 1088-token sequences
+DIM_MOE = 2048  # moonlight-16b-a3b
 
 
 @pytest.fixture(scope="module")
@@ -122,6 +124,32 @@ def test_flash_attention_block_classes_compile(one_chip, seq, window):
     _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
              ((4, seq, HQ, D), BF16), ((4, seq, HKV, D), BF16),
              ((4, seq, HKV, D), BF16))
+
+
+def test_flash_attention_mla_head_dims_compile(one_chip):
+    """Latent attention's heads (Moonlight-16B-A3B: 16 heads, q/k 192 wide,
+    v 128) at 8k, forward and backward, with the v5e tiles."""
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, block_q=256, block_k=512)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), one_chip,
+             ((1, 8192, 16, 192), BF16), ((1, 8192, 16, 192), BF16),
+             ((1, 8192, 16, 128), BF16))
+
+
+def test_grouped_matmul_compiles(one_chip):
+    """The expert layer's grouped product (megablox) at one 8k sequence's
+    rows (8192 x top-6) over 8 held experts of width 1408, forward and
+    backward, with the v5e tiles."""
+    from repro.kernels.gmm import grouped_matmul
+
+    def loss(x, w, sizes):
+        return grouped_matmul(x, w, sizes).astype(jnp.float32).sum()
+
+    _compile(jax.value_and_grad(loss, argnums=(0, 1)), one_chip,
+             ((8192 * 6, DIM_MOE), BF16), ((8, DIM_MOE, 1408), BF16),
+             ((8,), jnp.int32))
 
 
 @pytest.mark.parametrize("rows", [SLOTS, 4 * 1024])
