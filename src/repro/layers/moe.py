@@ -22,6 +22,7 @@ Interface-compatible with FeedForward: forward(x: (B,S,D)) -> (B,S,D).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple, Union
 
 import jax
@@ -36,6 +37,7 @@ from repro.core.config import (
 )
 from repro.core.utils import PartitionSpecLike, remat_name
 from repro.kernels import ops as kernel_ops
+from repro.kernels.gmm import tiling_for
 from repro.layers.base import (BaseLayer, KernelConfig, ParameterSpec,
                                fan_in_init, normal_init, zeros_init)
 from repro.layers.basic import get_activation
@@ -326,6 +328,124 @@ class SigmoidRouter(BaseLayer):
         return experts, gates
 
 
+# Chunk slots over the expected held rows of a sequence (DroplessMoE).
+_CHUNKS_PER_HELD = 6
+
+
+def _over_chunks(chunk: int, total: jax.Array, n: int, body, carry):
+    """``carry = body(carry, start)`` for each slot ``[start, start +
+    chunk)`` of ``n`` sorted rows that begins before ``total``; the other
+    slots run nothing. A scan of ``cond``s, not a ``vmap`` (which would run
+    both branches)."""
+    def slot(carry, start):
+        return jax.lax.cond(start < total, body, lambda c, _: c, carry,
+                            start), None
+
+    return jax.lax.scan(slot, carry, jnp.arange(0, n, chunk))[0]
+
+
+def _chunk_of(start, chunk, total, order, top_k):
+    """The sorted rows ``[start, start + chunk)``: their assignments (flat
+    ``(token, k)`` indices), their tokens, and which lie before ``total``
+    (as a column)."""
+    pos = jax.lax.dynamic_slice_in_dim(order, start, chunk)
+    live = (start + jnp.arange(chunk)) < total
+    return pos, pos // top_k, live[:, None]
+
+
+def _rows(a, start, chunk):
+    return jax.lax.dynamic_slice_in_dim(a, start, chunk)
+
+
+def _put(buf, rows, start):
+    return jax.lax.dynamic_update_slice_in_dim(buf, rows.astype(buf.dtype),
+                                               start, 0)
+
+
+def _scatter_add(parts, order, total, top_k, chunk, gates=None):
+    """(S, D) f32: each sorted row before ``total`` of the sum of the
+    ``parts`` buffers (times its assignment's gate), added at its token."""
+    def body(acc, start):
+        pos, token, live = _chunk_of(start, chunk, total, order, top_k)
+        rows = _rows(parts[0], start, chunk).astype(jnp.float32)
+        for part in parts[1:]:
+            rows = rows + _rows(part, start, chunk).astype(jnp.float32)
+        if gates is not None:
+            rows = rows * gates[pos][:, None]
+        return acc.at[token].add(jnp.where(live, rows, 0))
+
+    n, d = parts[0].shape
+    return _over_chunks(chunk, total, n, body,
+                        jnp.zeros((n // top_k, d), jnp.float32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _dispatch(x, order, total, top_k, chunk):
+    """x (S, D) -> the (S*K, D) sorted rows, twice: row i is ``x[order[i] //
+    K]`` before ``total`` and 0 after, gathered only in the chunks that
+    begin before ``total``. One copy for each of the two grouped products
+    that read it, so that their gradients are added chunk by chunk in the
+    backward (a scatter-add of the same chunks), not over the whole
+    buffer."""
+    def body(buf, start):
+        _, token, live = _chunk_of(start, chunk, total, order, top_k)
+        return _put(buf, jnp.where(live, x[token], 0), start)
+
+    rows = _over_chunks(chunk, total, order.shape[0], body,
+                        jnp.zeros((order.shape[0], x.shape[-1]), x.dtype))
+    return rows, rows
+
+
+def _dispatch_fwd(x, order, total, top_k, chunk):
+    return _dispatch(x, order, total, top_k, chunk), (order, total)
+
+
+def _dispatch_bwd(top_k, chunk, res, d_rows):
+    order, total = res
+    dx = _scatter_add(d_rows, order, total, top_k, chunk)
+    return dx.astype(d_rows[0].dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _combine(out, gates, order, total, top_k, chunk):
+    """(S*K, D) sorted expert outputs and (S*K,) f32 gates in assignment
+    order -> (S, D) f32: each sorted row before ``total`` times its gate,
+    added at its token, chunk by chunk. The gradient gathers the same
+    chunks: no (S, K, D) copy in either direction."""
+    return _scatter_add((out,), order, total, top_k, chunk, gates)
+
+
+def _combine_fwd(out, gates, order, total, top_k, chunk):
+    return (_combine(out, gates, order, total, top_k, chunk),
+            (out, gates, order, total))
+
+
+def _combine_bwd(top_k, chunk, res, dy):
+    out, gates, order, total = res
+
+    def body(carry, start):
+        d_out, d_gates = carry
+        pos, token, live = _chunk_of(start, chunk, total, order, top_k)
+        dy_rows = dy[token]
+        d_out = _put(d_out, jnp.where(live, dy_rows * gates[pos][:, None], 0),
+                     start)
+        d_g = jnp.sum(_rows(out, start, chunk).astype(jnp.float32) * dy_rows,
+                      axis=-1)
+        d_gates = d_gates.at[pos].set(jnp.where(live[:, 0], d_g, 0),
+                                      unique_indices=True)
+        return d_out, d_gates
+
+    d_out, d_gates = _over_chunks(chunk, total, order.shape[0], body,
+                                  (jnp.zeros_like(out), jnp.zeros_like(gates)))
+    return d_out, d_gates, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 class DroplessMoE(BaseLayer):
     """Drop-in FFN: routed experts without capacity, plus shared experts.
 
@@ -340,11 +460,29 @@ class DroplessMoE(BaseLayer):
 
       y = sum over the held top-k of gate_i * FFN_i(x) + FFN_shared(x)
 
+    Only the held rows are moved. The stable sort puts a sequence's held
+    assignments first among its ``S * K`` sorted rows; the dispatch (which
+    gathers token rows into expert order) and the combine (which scales
+    each row by its gate, in f32, and adds it at its token) work on chunks
+    of ``C`` sorted rows, a ``cond`` per chunk slot in a ``scan``, and run
+    the slots that begin before the sequence's held total, which is read on
+    the device: about ``ceil(held / C)`` of the ``S * K / C``. Their
+    gradients are the same loops the other way round (a scatter-add of the
+    chunks for the dispatch, a gather for the combine). ``C`` follows from
+    shapes: the largest multiple of the grouped products' row tile that
+    divides ``S * K`` and is at most the expected held rows ``S * K * count
+    / num_experts`` over 6 (1,024 of 49,152 in the Moonlight cell), so a
+    sequence wastes at most ``C - 1`` rows. A sequence whose assignments
+    are all held runs every slot. The grouped products are not chunked:
+    one call per weight over the whole ``(S * K, .)`` buffer, whose grid
+    already covers only the held rows' tiles.
+
     Scopes: ``router`` (the router module), ``moe.dispatch`` (sort and
     gather into expert order), ``expert_gmm`` (the grouped products, forward
     and backward), ``moe.combine`` (the weighted sum back in token order).
-    Summaries: ``held_assignments`` (rows the held experts computed) and
-    ``held_load_max_over_mean`` (over the held experts).
+    Summaries: ``held_assignments`` (rows the held experts computed),
+    ``held_load_max_over_mean`` (over the held experts) and
+    ``held_chunk_share`` (chunk slots run over slots, the sequences' mean).
     """
 
     @config_class
@@ -392,6 +530,18 @@ class DroplessMoE(BaseLayer):
             initializer=fan_in_init(fan_in_axes=(-2,)), mesh_axes=axes)
             for name, (shape, axes) in zip(self._WEIGHTS, (up, up, down))}
 
+    def _chunk_rows(self, S: int) -> int:
+        """Rows in one dispatch/combine chunk of a sequence of ``S`` tokens:
+        the largest multiple of the grouped products' row tile that divides
+        ``S * K`` and is at most the expected held rows, ``S * K * count /
+        num_experts``, over ``_CHUNKS_PER_HELD``; at least the tile."""
+        cfg = self.config
+        n = S * cfg.top_k
+        tile = tiling_for(n, cfg.input_dim, cfg.hidden_dim)[0]
+        want = n * cfg.held_experts[1] // cfg.num_experts // _CHUNKS_PER_HELD
+        return max([c for c in range(tile, want + 1, tile) if n % c == 0],
+                   default=tile)
+
     def _sequence(self, weights, x, local, gates):
         """One sequence's routed part: x (S, D), local (S, K) held expert
         index or ``count`` (not held), gates (S, K). Returns (y (S, D) fp32,
@@ -399,16 +549,19 @@ class DroplessMoE(BaseLayer):
         cfg = self.config
         count = cfg.held_experts[1]
         S, K = local.shape
+        chunk = self._chunk_rows(S)
         with jax.named_scope("moe.dispatch"):
             flat = local.reshape(-1)
             # Stable: held experts' rows in expert order, then the rest.
             order = jnp.argsort(flat, stable=True)
-            sizes = jnp.bincount(flat, length=count + 1)[:count].astype(
-                jnp.int32)
-            # Rows past the held ones are never computed: the grouped
-            # product leaves them undefined, so every use is masked.
-            held = (jnp.arange(S * K) < jnp.sum(sizes))[:, None]
-            rows = jnp.where(held, x[order // K], 0)
+            # Counted by comparison: a bincount is a serial scatter-add.
+            sizes = jnp.sum(flat[:, None] == jnp.arange(count), axis=0,
+                            dtype=jnp.int32)
+            total = jnp.sum(sizes)
+            rows_0, rows_1 = _dispatch(x, order, total, K, chunk)
+        # Rows past the held ones are never computed: the grouped product
+        # leaves them undefined, so every use is masked.
+        held = (jnp.arange(S * K) < total)[:, None]
 
         def gmm(lhs, name):
             with jax.named_scope("expert_gmm"):
@@ -416,14 +569,10 @@ class DroplessMoE(BaseLayer):
                                                 kernel=self.kernel_config)
             return jnp.where(held, out, 0)
 
-        out = gmm(gmm(rows, "wi_0") * jax.nn.silu(gmm(rows, "wi_1")), "wo")
+        out = gmm(gmm(rows_0, "wi_0") * jax.nn.silu(gmm(rows_1, "wi_1")),
+                  "wo")
         with jax.named_scope("moe.combine"):
-            back = jnp.zeros_like(order).at[order].set(
-                jnp.arange(S * K, dtype=order.dtype))
-            gates = jnp.where(local < count, gates, 0.0)
-            y = jnp.einsum("skd,sk->sd",
-                           out[back].reshape(S, K, -1).astype(jnp.float32),
-                           gates)
+            y = _combine(out, gates.reshape(-1), order, total, K, chunk)
         return y, sizes
 
     def forward(self, x: jax.Array) -> jax.Array:
@@ -440,6 +589,11 @@ class DroplessMoE(BaseLayer):
         self.add_summary("held_assignments", jnp.sum(load))
         self.add_summary("held_load_max_over_mean",
                          jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0))
+        S = x.shape[1]
+        chunk = self._chunk_rows(S)
+        runs = -(-jnp.sum(sizes, axis=-1) // chunk)
+        self.add_summary("held_chunk_share",
+                         jnp.mean(runs) * chunk / (S * cfg.top_k))
         y = y.astype(x.dtype)
         if cfg.shared is not None:
             y = y + self.shared_experts(x)

@@ -25,6 +25,7 @@ from chipbench import harness, program  # noqa: E402
 from repro.configs import registry  # noqa: E402
 from repro.configs.common import ffn_cfg  # noqa: E402
 from repro.core.module import functional  # noqa: E402
+from repro.kernels import ops as kernel_ops  # noqa: E402
 from repro.layers.moe import DroplessMoE, SigmoidRouter  # noqa: E402
 
 CELL = "moonlight-16b-a3b-train-d6-e8.pretrain-8k"
@@ -209,3 +210,152 @@ def test_flops_per_token_of_the_cell(ref):
     assert 3 * ref.attention_flops_per_token(cfg, 8192) == pytest.approx(
         0.755e9, rel=0.01)
     assert ref.flops_per_token(cfg, 8192) == pytest.approx(2.64e9, rel=0.01)
+
+
+# ------------------------------------------- routing over held rows only
+
+
+def _whole_sort_sequence(layer, weights, x, local, gates):
+    """``DroplessMoE._sequence`` as it was before the chunked dispatch and
+    combine: every one of the S*K sorted rows gathered, masked, gathered
+    back and summed in f32 (the oracle)."""
+    cfg = layer.config
+    count = cfg.held_experts[1]
+    S, K = local.shape
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+    held = (jnp.arange(S * K) < jnp.sum(sizes))[:, None]
+    rows = jnp.where(held, x[order // K], 0)
+
+    def gmm(lhs, name):
+        out = kernel_ops.grouped_matmul(lhs, weights[name], sizes,
+                                        kernel=layer.kernel_config)
+        return jnp.where(held, out, 0)
+
+    out = gmm(gmm(rows, "wi_0") * jax.nn.silu(gmm(rows, "wi_1")), "wo")
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(S * K, dtype=order.dtype))
+    gates = jnp.where(local < count, gates, 0.0)
+    y = jnp.einsum("skd,sk->sd",
+                   out[back].reshape(S, K, -1).astype(jnp.float32), gates)
+    return y, sizes
+
+
+def _expert_layer(D, H, E, count, K):
+    return DroplessMoE.default_config().set(
+        name="ff", input_dim=D, hidden_dim=H, num_experts=E, top_k=K,
+        held_experts=(0, count)).instantiate(parent=None)
+
+
+# (input_dim, hidden_dim, experts, held, top_k, tokens, share of the
+# assignments routed to held experts; None: a top-k of distinct experts)
+ROUTINGS = {
+    "none_held": (16, 32, 8, 4, 2, 1024, 0.0),
+    "all_held": (16, 32, 8, 4, 2, 1024, 1.0),
+    "held_not_a_chunk_multiple": (16, 32, 8, 4, 2, 1024, 0.4),
+    "moonlight_widths": (2048, 1408, 64, 8, 6, 128, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTINGS))
+def test_chunked_routing_matches_the_whole_sort(case):
+    """The chunked dispatch and combine give the whole sort's value and its
+    gradients with respect to x, the gates and the three expert weights."""
+    D, H, E, count, K, S, share = ROUTINGS[case]
+    layer = _expert_layer(D, H, E, count, K)
+    keys = jax.random.split(jax.random.PRNGKey(11), 7)
+    shapes = {"wi_0": (count, D, H), "wi_1": (count, D, H),
+              "wo": (count, H, D)}
+    w = {n: jax.random.normal(k, s) / np.sqrt(s[-2])
+         for (n, s), k in zip(shapes.items(), keys)}
+    x = jax.random.normal(keys[3], (S, D))
+    gates = jax.random.uniform(keys[4], (S, K))
+    if share is None:
+        experts = jnp.argsort(jax.random.uniform(keys[5], (S, E)), -1)[:, :K]
+        local = jnp.where(experts < count, experts, count)
+    else:
+        local = jnp.where(jax.random.uniform(keys[5], (S, K)) < share,
+                          jax.random.randint(keys[6], (S, K), 0, count),
+                          count)
+    probe = jnp.cos(jnp.arange(S * D, dtype=jnp.float32)).reshape(S, D)
+
+    def objective(fn):
+        def f(w, x, gates):
+            y, sizes = fn(w, x, local, gates)
+            return jnp.sum(y * probe), (y, sizes)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, (y, sizes)), grads = objective(layer._sequence)(w, x, gates)
+    (_, (y0, sizes0)), grads0 = objective(
+        lambda *a: _whole_sort_sequence(layer, *a))(w, x, gates)
+    chunk, total = layer._chunk_rows(S), int(np.sum(sizes0))
+    runs = -(-total // chunk)
+    if case == "none_held":
+        assert total == 0
+    elif case == "all_held":
+        assert total == S * K and runs == S * K // chunk > 1
+    elif case == "held_not_a_chunk_multiple":
+        assert total % chunk and 1 < runs < S * K // chunk
+    np.testing.assert_array_equal(np.asarray(sizes), np.asarray(sizes0))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y0), rtol=TOL,
+                               atol=TOL)
+    for name, got, want in zip(("wi_0", "wi_1", "wo", "x", "gates"),
+                               [*(grads[0][k] for k in shapes), *grads[1:]],
+                               [*(grads0[0][k] for k in shapes),
+                                *grads0[1:]]):
+        scale = float(np.max(np.abs(np.asarray(want)))) or 1.0
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=TOL, atol=TOL * scale, err_msg=name)
+
+
+def test_held_chunk_share_counts_the_chunks_run():
+    """``held_chunk_share`` reads ceil(held / C) / (S*K / C), averaged over
+    the sequences, and ``held_assignments`` is the whole sort's count."""
+    B, S, D = 3, 1024, 16
+    layer = _expert_layer(D, 32, 8, 2, 2)
+    keys = jax.random.split(jax.random.PRNGKey(12), 5)
+    state = {"router": {"weight": jax.random.normal(keys[0], (D, 8)),
+                        "bias": jnp.zeros((8,))},
+             "wi_0": jax.random.normal(keys[1], (2, D, 32)),
+             "wi_1": jax.random.normal(keys[2], (2, D, 32)),
+             "wo": jax.random.normal(keys[3], (2, 32, D))}
+    x = jax.random.normal(keys[4], (B, S, D))
+    _, col = functional(layer, state=state, inputs=(x,))
+    (experts, _), _ = functional(layer.router, state=state["router"],
+                                 inputs=(x,))
+    local = jnp.where(experts < 2, experts, 2)
+    held = [int(np.sum(np.asarray(local[b]) < 2)) for b in range(B)]
+    chunk, slots = layer._chunk_rows(S), S * 2 // layer._chunk_rows(S)
+    want = np.mean([-(-h // chunk) for h in held]) / slots
+    assert 0 < want < 1 and any(h % chunk for h in held)
+    np.testing.assert_allclose(float(col.summaries["held_chunk_share"]),
+                               want, rtol=1e-6)
+    sizes0 = [_whole_sort_sequence(layer, state, x[b], local[b],
+                                   jnp.ones((S, 2)))[1] for b in range(B)]
+    assert float(col.summaries["held_assignments"]) == sum(
+        int(np.sum(s)) for s in sizes0) == sum(held)
+
+
+def test_chunk_rule_and_one_grouped_product_per_weight(monkeypatch):
+    """At the cell's shapes a chunk is a multiple of the grouped products'
+    row tile that divides S*K, and a sequence still makes one grouped
+    product per expert weight."""
+    from repro.kernels.gmm import tiling_for
+
+    layer = _expert_layer(2048, 1408, 64, 8, 6)
+    S, K = 8192, 6
+    chunk = layer._chunk_rows(S)
+    tile = tiling_for(S * K, 2048, 1408)[0]
+    assert chunk % tile == 0 and (S * K) % chunk == 0
+    assert 3 <= S * K * 8 // 64 // chunk <= 12
+    calls = []
+    real = kernel_ops.grouped_matmul
+    monkeypatch.setattr(kernel_ops, "grouped_matmul",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    w = {"wi_0": jnp.zeros((8, 2048, 1408)), "wi_1": jnp.zeros((8, 2048, 1408)),
+         "wo": jnp.zeros((8, 1408, 2048))}
+    jax.eval_shape(layer._sequence, w, jnp.zeros((S, 2048), jnp.bfloat16),
+                   jnp.zeros((S, K), jnp.int32), jnp.zeros((S, K)))
+    assert len(calls) == 3
