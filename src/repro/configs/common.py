@@ -224,7 +224,7 @@ def lm_input_specs(shape: str, *, vocab_size: int, modality: str = "text",
 
 @dataclasses.dataclass
 class ArchSpec:
-    """Everything the launcher/benchmarks need to know about one arch."""
+    """Everything the launcher needs to know about one arch."""
 
     arch_id: str
     family: str  # dense | moe | hybrid | ssm | vlm | audio

@@ -1,8 +1,7 @@
 """llama2-7b — the paper's own evaluation model (Tables 3-4): 32L d=4096
 32H MHA d_ff=11008 vocab=32000 [arXiv:2307.09288].
 
-Not part of the assigned pool; included so the paper's performance tables
-have a direct counterpart in benchmarks/.
+Not part of the assigned pool.
 """
 
 from repro.configs import common as c

@@ -1,10 +1,10 @@
 """Shared utilities: ambient mesh, sharding helpers, tree helpers, remat tags.
 
 Config-based parallelism (paper §4.2) works by layers carrying partition
-specs over *named axes*; at trace time the ambient mesh (set by the trainer /
-dry-run launcher) resolves the names. Axis names absent from the active mesh
-are dropped, so the same config runs on a 1-CPU test mesh and a 512-chip
-production mesh unchanged.
+specs over *named axes*; at trace time the ambient mesh (set by the trainer)
+resolves the names. Axis names absent from the active mesh are dropped, so
+the same config runs on a 1-CPU test mesh and a 512-chip production mesh
+unchanged.
 """
 
 from __future__ import annotations

@@ -4,8 +4,7 @@ The engine reuses the exact training modules — the KV cache is an
 encapsulated component of each token mixer, so the engine only moves opaque
 state pytrees. Supports:
 
-  * prefill + single-token decode (``serve_step``): the function the decode
-    dry-run shapes lower,
+  * prefill + single-token decode (``serve_step``),
   * batched generation as ONE device program: a jitted ``lax.scan`` decode
     loop with fused on-device greedy/temperature sampling (PRNG key threaded
     through the carry) — a 256-token generation costs one dispatch + one
@@ -20,10 +19,9 @@ state pytrees. Supports:
 
 All jitted callables are built once and cached on the engine, so repeated
 ``generate``/``serve`` calls hit the jit trace cache instead of recompiling.
-TTFT/TPOT benchmarks (paper Table 4) run on this engine; the decode-step
-attention kernel is resolved by the kernel registry from each layer's
-``KernelConfig`` (op ``attention.decode``: Pallas flash-decode where capable,
-ref otherwise) — a config knob, not a code change (§4.2).
+The decode-step attention kernel is resolved by the kernel registry from
+each layer's ``KernelConfig`` (op ``attention.decode``: Pallas flash-decode
+where capable, ref otherwise) — a config knob, not a code change (§4.2).
 
 The paged serving subsystem (``repro.serving``: page allocator, chunked
 prefill scheduler, streaming gateway) layers on this engine's builders;
@@ -215,8 +213,8 @@ class InferenceEngine(Module):
     def serve_step_fn(self) -> Callable:
         """(params, cache, ids_step (B,1)) -> (cache, logits (B,V)).
 
-        ONE new token against a full-length KV cache — the decode dry-run
-        shape. The scan decode loop and continuous batching both build on it.
+        ONE new token against a full-length KV cache. The scan decode loop
+        and continuous batching both build on it.
         """
         model = self.model
 

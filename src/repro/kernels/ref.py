@@ -123,12 +123,11 @@ def blockwise_attention(
     logit_softcap: Optional[float] = None,
     scale: Optional[float] = None,
     chunk_size: int = 512,
-    unroll: bool = False,
 ) -> jax.Array:
     """Query-chunked attention: O(chunk * T) live memory, pure XLA.
 
-    This is the portable production path (used by the multi-pod dry-run);
-    mathematically identical to :func:`reference_attention`.
+    This is the portable production path; mathematically identical to
+    :func:`reference_attention`.
     """
     B, S, Hq, D = q.shape
     _, T, Hkv, _ = k.shape
@@ -164,11 +163,9 @@ def blockwise_attention(
         return jnp.einsum("bkgst,btkd->bskgd", probs.astype(v.dtype), v,
                           preferred_element_type=jnp.float32).astype(q.dtype)
 
-    # Scanned so one chunk's logits are live at a time (unroll for AOT
-    # analysis mode: exact cost_analysis).
+    # Scanned so one chunk's logits are live at a time.
     _, out = jax.lax.scan(lambda c, xs: (c, one_chunk(xs)), 0,
-                          (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(qp, 1, 0)),
-                          unroll=unroll)
+                          (jnp.moveaxis(qc, 1, 0), jnp.moveaxis(qp, 1, 0)))
     out = jnp.moveaxis(out, 0, 1).reshape(B, S, Hq, v.shape[-1])
     return out
 
@@ -218,7 +215,6 @@ def reference_wkv6_recurrent(
 def reference_wkv6(
     r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array, u: jax.Array,
     state: Optional[jax.Array] = None, *, chunk_size: int = 64,
-    unroll: bool = False,
 ):
     """Chunked (parallel-within-chunk) WKV6 — same math as the recurrence.
 
@@ -269,6 +265,6 @@ def reference_wkv6(
         s = jnp.exp(total)[..., None] * s + jnp.einsum("bjhk,bjhv->bhkv", k_dec, v_c)
         return s, o
 
-    final, out = jax.lax.scan(chunk_step, state, (rf, kf, vf, wf), unroll=unroll)
+    final, out = jax.lax.scan(chunk_step, state, (rf, kf, vf, wf))
     out = jnp.moveaxis(out, 0, 1).reshape(B, T, H, V)
     return out.astype(r.dtype), final

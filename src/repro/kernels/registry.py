@@ -31,8 +31,8 @@ auto-selected unless ``KernelConfig.interpret=True`` (it is slow), but can
 always be requested explicitly.
 
 Resolution is memoized: the (op, backend, features) triple is hashable and
-the cached lookup is a single dict hit (<1µs — see ``bench_kernels``), so
-dispatch adds no per-call or per-trace overhead on hot paths.
+the cached lookup is a single dict hit, so dispatch adds no per-call or
+per-trace overhead on hot paths.
 """
 
 from __future__ import annotations
@@ -94,10 +94,8 @@ class KernelConfig(ConfigBase):
     decode_block_k: int = 256
     # XLA blockwise attention (query-chunked scan).
     blockwise_chunk_size: int = 512
-    blockwise_unroll: bool = False
     # WKV6 chunk length (Pallas grid / ref scan).
     wkv_chunk_size: int = 64
-    wkv_unroll: bool = False
     # Pallas RMSNorm row tile.
     rmsnorm_block_rows: int = 256
 
@@ -156,8 +154,7 @@ class KernelFeatures:
 
     def __post_init__(self):
         # Hash once at construction: dispatch-cache lookups are on the
-        # trace hot path and must not re-hash 12 fields per call (<1µs
-        # amortized resolve budget, see bench_kernels).
+        # trace hot path and must not re-hash 12 fields per call.
         object.__setattr__(self, "_hash", hash((
             self.platform, self.dtype, self.interpret, self.explicit,
             self.needs_grad, self.ragged_positions, self.single_query,
@@ -495,7 +492,7 @@ def _register_builtin_specs() -> None:
             q, k, v, q_positions=q_positions, k_positions=k_positions,
             causal=causal, sliding_window=sliding_window,
             logit_softcap=logit_softcap, scale=scale,
-            chunk_size=cfg.blockwise_chunk_size, unroll=cfg.blockwise_unroll)
+            chunk_size=cfg.blockwise_chunk_size)
 
     def _fwd_ref(q, k, v, *, q_positions, k_positions, causal,
                  sliding_window, logit_softcap, scale, cfg):
@@ -625,8 +622,7 @@ def _register_builtin_specs() -> None:
 
     def _wkv6_ref(r, k, v, w, u, state, *, cfg):
         return _ref.reference_wkv6(r, k, v, w, u, state,
-                                   chunk_size=cfg.wkv_chunk_size,
-                                   unroll=cfg.wkv_unroll)
+                                   chunk_size=cfg.wkv_chunk_size)
 
     # wkv6.decode: the O(1) recurrent step (ref-only today — a Pallas
     # recurrent-step kernel registers here without touching rwkv.py).

@@ -41,8 +41,8 @@ Worker mode (what the fleet supervisor spawns)::
 
 Fault-injection flags (used by the supervisor's drills):
 ``--sigkill-at-step S`` raises SIGKILL against itself in the step hook of
-step S (exact step boundary; if S just launched an async save, the write is
-in flight — the mid-save kill); ``--sigterm-at-step S`` sets the preemption
+step S (exact step boundary, after any earlier save has committed; if S just
+launched an async save, the write is in flight — the mid-save kill); ``--sigterm-at-step S`` sets the preemption
 event at step S (the SIGTERM drill, deterministic at a boundary);
 ``--kill-during-save-step S`` dies INSIDE ``_write_step`` of the save for
 step S after leaving a torn tmp shard behind — the torn-commit scenario.
@@ -398,6 +398,13 @@ def run_worker(argv: Optional[List[str]] = None) -> int:
         if args.sigterm_at_step is not None and step == args.sigterm_at_step:
             trainer.preemption_event.set()
         if args.sigkill_at_step is not None and step == args.sigkill_at_step:
+            # A save launched at an earlier step commits first, so the
+            # resume point does not hang on how fast this host writes; one
+            # launched at this step is still in flight when the kill lands.
+            tcfg = trainer.config
+            if (tcfg.checkpointer is not None and tcfg.checkpoint_every_n
+                    and (step + 1) % tcfg.checkpoint_every_n):
+                trainer.checkpointer.wait()
             os.kill(os.getpid(), signal.SIGKILL)
 
     try:

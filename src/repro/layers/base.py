@@ -2,9 +2,8 @@
 
 Parameters are declared as :class:`ParameterSpec` (shape, dtype, initializer,
 ``mesh_axes``) — the mesh_axes carry the *named-axis* partition spec that the
-paper's config-based parallelism (§4.2) hinges on. The trainer and the AOT
-dry-run consume the spec tree to build NamedShardings; layers never touch
-devices.
+paper's config-based parallelism (§4.2) hinges on. The trainer consumes
+the spec tree to build NamedShardings; layers never touch devices.
 
 Mixed precision is a :class:`DtypePolicy` carried by every layer config:
 inputs are cast to ``compute_dtype`` at module boundaries (layers already

@@ -52,8 +52,6 @@ class CausalLM(BaseLayer):
         # Token-chunked CE: never materializes (B, S, V) logits — required to
         # fit 256k-vocab training at 1M tokens/step. None = single-shot.
         loss_chunk_size: Optional[int] = None
-        # Unroll the chunk scan (AOT analysis mode: exact cost_analysis).
-        loss_chunk_unroll: bool = False
 
     def __init__(self, cfg, *, parent=None):
         super().__init__(cfg, parent=parent)
@@ -119,8 +117,7 @@ class CausalLM(BaseLayer):
         body = jax.checkpoint(body, prevent_cse=False)
         (nll_sum, correct, count), _ = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
-                   jnp.zeros((), jnp.int32)), (hs, labels),
-            unroll=cfg.loss_chunk_unroll)
+                   jnp.zeros((), jnp.int32)), (hs, labels))
         denom = jnp.maximum(count, 1)
         loss = nll_sum / denom
         acc = correct / denom

@@ -51,8 +51,8 @@ class RWKV6TimeMix(BaseLayer):
         proj_weight_partition: PartitionSpecLike = ("data", "model")
         out_weight_partition: PartitionSpecLike = ("model", "data")
         hidden_partition: PartitionSpecLike = (("pod", "data"), None, "model")
-        # Registry dispatch for the "wkv6" op (paper §4.2); wkv_chunk_size /
-        # wkv_unroll tiling also lives on the shared KernelConfig.
+        # Registry dispatch for the "wkv6" op (paper §4.2); wkv_chunk_size
+        # tiling also lives on the shared KernelConfig.
         kernel: KernelConfig = KernelConfig()
 
     @property

@@ -62,7 +62,6 @@ class MambaMixer(BaseLayer):
         # fp32 (B, chunk, d_inner, N) working set instead of materializing
         # log-depth (B, S, d_inner, N) buffers.
         scan_chunk_size: int = 256
-        scan_unroll_chunks: bool = False
 
     def __init__(self, cfg, *, parent=None):
         super().__init__(cfg, parent=parent)
@@ -185,12 +184,10 @@ class MambaMixer(BaseLayer):
                 body = jax.checkpoint(
                     lambda h, xv: self._scan_chunk(h, xv[0], xv[1]),
                     prevent_cse=False)
-                h_final, ys = jax.lax.scan(body, h0, (xs, vs),
-                                           unroll=cfg.scan_unroll_chunks)
+                h_final, ys = jax.lax.scan(body, h0, (xs, vs))
             else:
                 body = jax.checkpoint(self._scan_chunk, prevent_cse=False)
-                h_final, ys = jax.lax.scan(body, h0, xs,
-                                           unroll=cfg.scan_unroll_chunks)
+                h_final, ys = jax.lax.scan(body, h0, xs)
             y = jnp.moveaxis(ys, 0, 1).reshape(B, S, di)
 
         y = y + self.state["D"] * x_conv.astype(jnp.float32)

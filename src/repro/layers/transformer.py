@@ -7,8 +7,7 @@ Composition rules (the paper's modularity story):
   * ``TransformerLayer.feed_forward`` is ANY FFN-compatible module (dense FFN,
     MoE, residual-MoE) — MoE is a drop-in replacement (§2.1).
   * ``Repeat`` stacks identical layers (or identical heterogeneous *blocks*)
-    with ``lax.scan`` over stacked params — keeping HLO size O(1) in depth,
-    which is what makes 72-layer × 512-chip AOT dry-runs tractable.
+    with ``lax.scan`` over stacked params — keeping HLO size O(1) in depth.
 """
 
 from __future__ import annotations
@@ -257,10 +256,6 @@ class Repeat(BaseLayer):
         # repro.trainer.remat.policy_from_spec (e.g. "full",
         # "save:attn_out,ffn_out", "offload:ffn_hidden").
         remat_policy: Optional[str] = "full"
-        # lax.scan unroll factor. True = fully unroll — used by the AOT
-        # dry-run so cost_analysis counts every layer (XLA tallies a while
-        # body once), at the cost of larger HLO.
-        scan_unroll: Any = 1
         # Reversible two-stream residual stack (repro.memopt.reversible):
         # the backward pass reconstructs activations from the layers'
         # invertible structure instead of saving them — O(1) activation
@@ -358,7 +353,7 @@ class Repeat(BaseLayer):
             xs["key"] = keys
         if per_layer_state is not None:
             xs["state"] = per_layer_state
-        return jax.lax.scan(body, carry_x, xs, unroll=cfg.scan_unroll)
+        return jax.lax.scan(body, carry_x, xs)
 
     # --- public interface -------------------------------------------------------
 

@@ -35,7 +35,7 @@ autodiff to the same order.
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +75,7 @@ def _zero_cotangent(x):
 
 
 def rev_stack(layer, params, x1, x2, positions=None, *, is_training=True,
-              unroll: Any = 1, use_custom_vjp: bool = True):
+              use_custom_vjp: bool = True):
     """Runs the two-stream reversible scan over stacked ``params``.
 
     ``layer`` is the (shared) inner module; ``params`` its stacked weights
@@ -100,7 +100,7 @@ def rev_stack(layer, params, x1, x2, positions=None, *, is_training=True,
             y2 = h2 + call(p_i, "ffn_branch", y1, pos)
             return (y1, y2), None
 
-        (y1, y2), _ = jax.lax.scan(step, (x1, x2), params, unroll=unroll)
+        (y1, y2), _ = jax.lax.scan(step, (x1, x2), params)
         return y1, y2
 
     if not use_custom_vjp:
@@ -141,7 +141,7 @@ def rev_stack(layer, params, x1, x2, positions=None, *, is_training=True,
             return (x1, x2, dx1, dx2), dp
 
         (_, _, dx1, dx2), dparams = jax.lax.scan(
-            back, (y1, y2, dy1, dy2), params, reverse=True, unroll=unroll)
+            back, (y1, y2, dy1, dy2), params, reverse=True)
         dpos = jax.tree.map(_zero_cotangent, pos)
         return dparams, dx1, dx2, dpos
 
@@ -159,7 +159,7 @@ def reversible_forward(repeat, x, positions: Optional[jax.Array] = None):
     ctx = repeat._ctx
     y1, y2 = rev_stack(
         repeat.layer, params, x, x, positions,
-        is_training=ctx.is_training, unroll=repeat.config.scan_unroll)
+        is_training=ctx.is_training)
     # Merge by averaging: keeps the output magnitude of one stream (the
     # final RMSNorm sees the same scale as a single-stream stack).
     return 0.5 * (y1 + y2)

@@ -6,8 +6,7 @@ and the fleet workers' goodput streams all flow into a
 :class:`MetricsRegistry` so a run has a single, uniformly-schemed telemetry
 stream instead of per-subsystem ad-hoc lists.
 
-Design constraints (enforced by ``tests/test_observability.py`` and
-``benchmarks/bench_observability.py``):
+Design constraints (enforced by ``tests/test_observability.py``):
 
 * **Hot-path cost is a dict update.** ``Counter.inc`` / ``Gauge.set`` /
   ``Histogram.record`` touch only in-process state — no I/O, no locks, no

@@ -9,7 +9,7 @@ request: queued → prefill → decode lanes on the serving side.
 
 All tracing is host-side and outside jit — a span is two ``time`` calls
 and a dict append, so instrumenting the hot loop adds zero retraces and
-microsecond-scale per-span cost (measured by ``bench_observability``).
+microsecond-scale per-span cost.
 A ``span`` is also a ``jax.profiler.TraceAnnotation`` of the same name, so
 while a profiler capture is open (``ProfilerWindow``) the phase appears on
 the device trace's clock beside the device ops it dispatched.

@@ -59,9 +59,8 @@ FP8_E4M3_KV = KVQuantFormat("fp8_e4m3", jnp.float8_e4m3fn,
 
 _BY_NAME = {f.name: f for f in (INT8_KV, FP8_E4M3_KV)}
 
-# Serving/bench-facing dtype names -> storage dtypes (the only place the
-# string names resolve, so benchmarks and launch scripts never spell a
-# low-precision dtype).
+# Serving-facing dtype names -> storage dtypes (the only place the string
+# names resolve, so launch scripts never spell a low-precision dtype).
 _DTYPE_NAMES = {
     "fp32": jnp.float32,
     "bf16": jnp.bfloat16,

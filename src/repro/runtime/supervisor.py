@@ -214,9 +214,10 @@ class FleetFault:
 
     ``sigkill``   — SIGKILL rank ``rank`` at the boundary of ``step`` (the
                     worker kills itself in its step hook, so the kill lands
-                    at an exact step — and mid-async-save if ``step`` just
-                    launched one). Peers block in the next collective until
-                    it times out; the supervisor reaps everyone.
+                    at an exact step, after any earlier save has committed
+                    — and mid-async-save if ``step`` just launched one).
+                    Peers block in the next collective until it times out;
+                    the supervisor reaps everyone.
     ``sigterm``   — cluster preemption notice: EVERY rank sets its
                     preemption event at ``step`` (an individual-rank SIGTERM
                     would deadlock peers waiting in step collectives while

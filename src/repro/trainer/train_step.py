@@ -1,7 +1,7 @@
 """Composable SPMD train-step builder (paper §3–§5).
 
 ``SpmdTrainer.make_train_step`` used to be one monolithic closure; the
-pieces now compose so launchers/benchmarks can build custom steps from the
+pieces now compose so launchers can build custom steps from the
 same parts the trainer uses:
 
   * :func:`make_loss_fn` — model forward + aux-loss aggregation.

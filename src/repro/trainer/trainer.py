@@ -2,9 +2,7 @@
 
 Everything is a replaceable child module: model, learner, input pipeline,
 checkpointer. Parallelism is configured — mesh shape + axis names + the
-partition specs the layers already carry — never coded (§4.2). The exact
-``train_step`` built here is what the AOT dry-run lowers, fulfilling the
-paper's "a program that AOT-compiles will run at scale" property.
+partition specs the layers already carry — never coded (§4.2).
 """
 
 from __future__ import annotations
@@ -148,8 +146,7 @@ class SpmdTrainer(Module):
         # per-device optimizer bytes shrink ~Nx on an N-way data mesh.
         opt_state_sharding: str = "params"
         zero1_axes: Tuple[str, ...] = ("pod", "data")
-        # Optimizer-state host offload (TPU feature; see DESIGN.md for the
-        # CPU dry-run substitution).
+        # Optimizer-state host offload (TPU feature).
         offload_optimizer_state: bool = False
         # Unified observability (repro.observability): metrics registry +
         # JSONL sink, Chrome trace spans per step phase, HFU/memory gauges,

@@ -6,6 +6,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
+from repro.configs import registry
 from repro.core.config import (
     REQUIRED,
     ConfigBase,
@@ -20,6 +21,9 @@ from repro.core.config import (
     replace_config,
     visit_config,
 )
+from repro.layers import FeedForward
+from repro.layers.moe import MoELayer
+from repro.layers.rope import LinearScaledRotaryEmbedding, RotaryEmbedding
 
 
 @config_class
@@ -205,3 +209,62 @@ def test_replace_is_idempotent_property(cfg, dim):
     n2 = replace_config(cfg, target=_InnerCfg, new_cfg=_AltInnerCfg(), propagate=("dim",))
     assert n1 == 1 and n2 == 0
     assert cfg.inner.dim == dim
+
+
+# --- Paper Table 2: a feature variant is one replace_config call ------------
+# Each snippet integrates its variant into any experiment config, with no
+# change to layer code. Only configs are built; nothing is instantiated.
+
+
+def _integrate_rope_variant(experiment_cfg):
+    return replace_config(
+        experiment_cfg,
+        target=RotaryEmbedding,
+        new_cfg=LinearScaledRotaryEmbedding.default_config().set(
+            scaling_factor=4.0),
+        propagate=("dim", "theta", "rotary_pct"),
+    )
+
+
+def _integrate_moe(experiment_cfg):
+    return replace_config(
+        experiment_cfg,
+        target=FeedForward,
+        new_cfg=MoELayer.default_config().set(num_experts=4, top_k=2),
+        propagate=("input_dim", "hidden_dim"),
+    )
+
+
+def _sites(cfg, layer):
+    found = []
+
+    def visit(_, c):
+        if isinstance(c, layer.Config):
+            found.append(c)
+
+    visit_config(cfg, visit)
+    return found
+
+
+# Archs whose configs hold the target layer: RoPE everywhere but jamba (no
+# positional embedding), rwkv6 (recurrent) and hubert (convolutional); a
+# dense FFN everywhere but mixtral (every FFN is MoE) and rwkv6 (channel mix).
+_HAS_ROPE = {"qwen2-1.5b", "phi-3-vision-4.2b", "qwen1.5-4b", "mixtral-8x7b",
+             "arctic-480b", "gemma2-27b", "internlm2-1.8b"}
+_HAS_FFN = {"qwen2-1.5b", "phi-3-vision-4.2b", "qwen1.5-4b",
+            "jamba-1.5-large-398b", "arctic-480b", "gemma2-27b",
+            "hubert-xlarge", "internlm2-1.8b"}
+
+
+@pytest.mark.parametrize("arch", registry.ASSIGNED_ARCHS)
+def test_feature_integration_is_one_replace_config_call(arch):
+    cfg = registry.get_spec(arch).make_model()
+    for integrate, old, new, has in (
+            (_integrate_rope_variant, RotaryEmbedding,
+             LinearScaledRotaryEmbedding, arch in _HAS_ROPE),
+            (_integrate_moe, FeedForward, MoELayer, arch in _HAS_FFN)):
+        expected = len(_sites(cfg, old))
+        assert (expected > 0) == has, (arch, old.__name__, expected)
+        assert integrate(cfg) == expected, (arch, old.__name__)
+        assert all(isinstance(c, new.Config) for c in _sites(cfg, old))
+        config_to_dict(cfg)

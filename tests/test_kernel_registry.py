@@ -338,22 +338,81 @@ def test_kernel_modifier_is_ten_line_backend_story():
         reg.clear_dispatch_cache()
 
 
-def test_bench_kernels_parity_cases_all_match_ref():
-    """Every parity case of ``benchmarks/bench_kernels.py`` runs on the CPU
-    (Pallas interpreted) and matches the ref oracle: a kernel layout change
-    cannot break the benchmark harness unseen."""
-    import importlib.util
+# Interpret-mode Pallas is slow; keep parity shapes small.
+_B, _S, _T, _H, _HKV, _D = 2, 64, 64, 4, 2, 16
 
-    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
-                        "bench_kernels.py")
-    spec = importlib.util.spec_from_file_location("bench_kernels", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    bench.run()
-    parity = bench.LAST_JSON["parity"]
-    cases = bench._parity_cases()
-    assert {op for op, _, _ in cases} == set(parity)
-    assert sum(len(rows) for rows in parity.values()) == len(cases)
-    bad = {(op, backend): row for op, rows in parity.items()
-           for backend, row in rows.items() if not row["ok"]}
-    assert not bad, bad
+
+def _attention_qkv(Sq):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    return (jax.random.normal(ks[0], (_B, Sq, _H, _D)),
+            jax.random.normal(ks[1], (_B, _T, _HKV, _D)),
+            jax.random.normal(ks[2], (_B, _T, _HKV, _D)))
+
+
+def _fwd_case(kc):
+    q, k, v = _attention_qkv(_S)
+    return (ops.flash_attention(q, k, v, kernel=kc),
+            ref.reference_attention(q, k, v))
+
+
+def _decode_case(kc):
+    q, k, v = _attention_qkv(1)
+    k_pos = jnp.broadcast_to(jnp.arange(_T), (_B, _T))
+    q_pos = jnp.full((_B, 1), _T)
+    # Decode reads a head-major (B, Hkv, T, D) cache; the oracle takes
+    # token-major K/V.
+    out = ops.decode_attention(q, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2),
+                               q_positions=q_pos, k_positions=k_pos, kernel=kc)
+    return out, ref.reference_attention(q, k, v, q_positions=q_pos,
+                                        k_positions=k_pos)
+
+
+def _rmsnorm_case(kc):
+    x = jax.random.normal(jax.random.PRNGKey(1), (_B, _S, 64))
+    scale = jax.random.normal(jax.random.PRNGKey(2), (64,))
+    return ops.rmsnorm(x, scale, kernel=kc), ref.reference_rmsnorm(x, scale)
+
+
+def _wkv6_case(kc):
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    r, k, v = (jax.random.normal(key, (_B, _S, 2, 8)) for key in ks[:3])
+    w = jax.random.uniform(ks[3], (_B, _S, 2, 8), minval=0.6, maxval=0.99)
+    u = jax.random.normal(ks[4], (2, 8)) * 0.5
+    return (ops.wkv6(r, k, v, w, u, kernel=kc)[0],
+            ref.reference_wkv6(r, k, v, w, u, chunk_size=16)[0])
+
+
+@pytest.mark.parametrize("op,backend,case", [
+    pytest.param("attention.fwd", "blockwise", _fwd_case,
+                 id="attention.fwd-blockwise"),
+    pytest.param("attention.fwd", "pallas", _fwd_case,
+                 id="attention.fwd-pallas"),
+    pytest.param("attention.decode", "pallas", _decode_case,
+                 id="attention.decode-pallas"),
+    pytest.param("rmsnorm", "pallas", _rmsnorm_case, id="rmsnorm-pallas"),
+    pytest.param("wkv6", "pallas", _wkv6_case, id="wkv6-pallas"),
+])
+def test_backend_matches_ref(op, backend, case, monkeypatch):
+    """A backend forced through ``op_overrides`` is the one that runs, through
+    the ``ops`` entry point and its registry adapter, and matches the ref
+    oracle; Pallas runs interpreted off the TPU."""
+    on_tpu = reg.current_platform() == "tpu"
+    kc = KernelConfig().set(
+        op_overrides={op: backend},
+        interpret=not on_tpu and backend == "pallas",
+        blockwise_chunk_size=16, wkv_chunk_size=16)
+    ran = []
+    resolve = reg.resolve
+
+    def spy(op_, features, **kw):
+        spec = resolve(op_, features, **kw)
+        ran.append((op_, spec.backend))
+        return spec
+
+    monkeypatch.setattr(reg, "resolve", spy)
+    out, expect = case(kc)
+    assert ran == [(op, kc.backend_for(op))], ran
+    assert kc.backend_for(op) in (backend, backend + ":interpret")
+    err = np.max(np.abs(np.asarray(out, np.float32)
+                        - np.asarray(expect, np.float32)))
+    assert err < 5e-4, err

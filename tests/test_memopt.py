@@ -151,9 +151,8 @@ def test_int8_adam_first_step_matches_fp32():
 def test_int8_adam_converges_with_quantization_drag():
     """int8 moments optimize the same quadratic, slower: per-row symmetric
     quantization zeroes sub-resolution moment entries, which a tiny
-    deterministic quadratic amplifies far more than real training (LM-level
-    loss parity is asserted in BENCH_train.json's memopt block, ~1% at 60
-    steps). The contract here: steady convergence, bounded drag."""
+    deterministic quadratic amplifies far more than real training. The
+    contract here: steady convergence, bounded drag."""
     target = jax.random.normal(jax.random.PRNGKey(0), (16, 16))
     loss_fn = lambda p: jnp.mean((p["w"] - target) ** 2)  # noqa: E731
     losses = {}

@@ -525,10 +525,9 @@ def test_observability_overhead_under_budget(tmp_path):
     per-step gauges + histogram + MFU + delta flush into a real JSONL
     sink), measured in place during an instrumented run, NOT as an
     off-vs-on step-time A/B: on a sub-3ms toy CPU step under CI load the
-    A/B delta is dominated by scheduler/GC noise and flakes either way
-    (``bench_observability`` reports the exact interleaved-median delta,
-    for an idle machine). A companion bound pins the tracer's per-span
-    cost, so both halves of the hot path are enforced."""
+    A/B delta is dominated by scheduler/GC noise and flakes either way. A
+    companion bound pins the tracer's per-span cost, so both halves of the
+    hot path are enforced."""
     import statistics
     import time
 

@@ -8,7 +8,8 @@ import pytest
 from repro.core.config import config_for_function, replace_config
 from repro.core.module import functional
 from repro.layers import CausalLM, Decoder, Linear, Repeat, TransformerLayer
-from repro.layers.quantized import Int8ConfigModifier, QuantizedLinear, quantize_int8
+from repro.quantization.linear import (Int8ConfigModifier, QuantizedLinear,
+                                       quantize_int8)
 
 
 def test_quantize_int8_roundtrip_error_bounded():
